@@ -311,6 +311,8 @@ def main(argv: list | None = None) -> None:
                     help="rewrite the baseline artifacts in --baseline-dir "
                          "from this run (commit the result)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     registry = discover(args.modules or None)
     if args.list:

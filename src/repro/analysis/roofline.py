@@ -1,4 +1,5 @@
-"""Roofline terms for TPU v5e from per-device HLO cost (see hlo.py).
+"""Roofline terms from per-device HLO cost (see hlo.py), against the
+published peaks of the device the program runs on.
 
 All three terms are per-chip seconds for one step:
   compute_s    = flops_per_device / peak_flops
@@ -26,7 +27,21 @@ class HW:
     hbm_bytes: int = 16 * 2 ** 30
 
 
-V5E = HW()
+# Peak table keyed by ``jax.Device.device_kind``. TPU v5e: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of ICI per chip (4 links x 50 GB/s).
+PEAKS = {"TPU v5 lite": HW()}
+V5E = PEAKS["TPU v5 lite"]
+
+
+def peaks_for(device_kind: str) -> HW:
+    """Published peaks of one device kind; a kind missing from ``PEAKS``
+    is an error, never a silent default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peak table for device kind {device_kind!r}; "
+                         f"known kinds: {sorted(PEAKS)}") from None
 
 
 @dataclasses.dataclass

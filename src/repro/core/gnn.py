@@ -95,6 +95,14 @@ def forward(params: list, x: jax.Array, neighbors: jax.Array,
 
 
 @partial(jax.jit, static_argnames="cfg")
+def centralized_forward(params: list, feats: jax.Array, neighbors: jax.Array,
+                        weights: jax.Array, cfg: GNNConfig) -> jax.Array:
+    """``forward`` over a centralized plan's ``[1, N, ...]`` tables;
+    returns ``[1, N, out_dim]``."""
+    return forward(params, feats[0], neighbors[0], weights[0], cfg)[None]
+
+
+@partial(jax.jit, static_argnames="cfg")
 def loss_fn(params: list, x, neighbors, weights, labels, cfg: GNNConfig):
     """Cross-entropy node-classification loss (mean over labeled nodes)."""
     logits = forward(params, x, neighbors, weights, cfg)

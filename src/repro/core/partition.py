@@ -598,13 +598,29 @@ class ExecutionPlan:
         disabled (the default) the wrapper is a single flag check.
         """
         from repro.telemetry import instrument_forward
-        fwd = self._build_forward(cfg, mesh=mesh, mode=mode, overlap=overlap)
-        return instrument_forward(self, self.gnn_config(cfg), mode, fwd)
+        fn, args = self._forward_parts(cfg, mesh=mesh, mode=mode,
+                                       overlap=overlap)
+        return instrument_forward(self, self.gnn_config(cfg), mode,
+                                  lambda params: fn(params, *args))
 
-    def _build_forward(self, cfg, mesh=None, mode: str = "alltoall",
+    def lower_forward(self, params, cfg, mesh=None, mode: str = "alltoall"):
+        """``jax.stages.Lowered`` of the program ``make_forward`` runs, with
+        the plan's tables as arguments rather than embedded constants —
+        what a check that the kernels were compiled, not interpreted, reads
+        (``tpu_custom_call`` in ``as_text()``). Dense layouts only: the
+        bucketed forward is a host loop over buckets, not one program."""
+        if self.bucketed is not None:
+            raise ValueError("a bucketed plan's forward dispatches per "
+                             "bucket from the host; there is no single "
+                             "program to lower")
+        fn, args = self._forward_parts(cfg, mesh=mesh, mode=mode)
+        return fn.lower(params, *args)
+
+    def _forward_parts(self, cfg, mesh=None, mode: str = "alltoall",
                        overlap: str = "overlap"):
+        """``(fn, args)``: the forward is ``fn(params, *args)``, with
+        ``args`` this plan's device-resident tables."""
         import jax.numpy as jnp
-        from repro.core import gnn
         cfg = self.gnn_config(cfg)
         if self.bucketed is not None:
             from repro.distributed.halo import (
@@ -617,21 +633,17 @@ class ExecutionPlan:
                 fn = make_emulated_bucketed_semi_forward(
                     cfg, bplan, self.hier, self.bucketed, mode=mode,
                     overlap=overlap)
-                spoke = jnp.asarray(self.feats)
-                return lambda params: fn(params, spoke, nbrs, wtss)
+                return fn, (jnp.asarray(self.feats), nbrs, wtss)
             fn = make_emulated_bucketed_forward(cfg, bplan, mode=mode,
                                                 overlap=overlap)
             feats = tuple(jnp.asarray(f) for f in self.feats)
-            return lambda params: fn(params, feats, nbrs, wtss)
-        feats = jnp.asarray(self.feats)
-        nbr = jnp.asarray(self.neighbors)
-        wts = jnp.asarray(self.weights)
+            return fn, (feats, nbrs, wtss)
+        spmd = (mesh is not None and mesh.size == self.n_clusters
+                and self.setting != "centralized")
+        args = self.device_inputs(mesh if spmd else None)
         if self.setting == "centralized":
-            def forward(params):
-                return gnn.forward(params, feats[0], nbr[0], wts[0],
-                                   cfg)[None]
-            return forward
-        spmd = mesh is not None and mesh.size == self.n_clusters
+            from repro.core import gnn
+            return gnn.centralized_forward, (*args, cfg)
         if self.setting == "semi":
             from repro.distributed.halo import (build_two_tier_plan,
                                                 make_emulated_semi_forward,
@@ -639,7 +651,7 @@ class ExecutionPlan:
             plan = build_two_tier_plan(self.hier)
             fn = (make_semi_forward(mesh, cfg, plan, mode=mode) if spmd
                   else make_emulated_semi_forward(cfg, plan, mode=mode))
-            return lambda params: fn(params, feats, nbr, wts)
+            return fn, args
         from repro.distributed.halo import (build_halo_plan,
                                             make_decentralized_forward,
                                             make_emulated_forward)
@@ -649,7 +661,24 @@ class ExecutionPlan:
                                             mode=mode)
         else:
             fn = make_emulated_forward(cfg, plan, mode=mode)
-        return lambda params: fn(params, feats, nbr, wts)
+        return fn, args
+
+    def device_inputs(self, mesh=None):
+        """The dense plan's (feats, neighbors, weights) as device arrays.
+
+        With a ``mesh``, each is placed with a ``NamedSharding`` that splits
+        the leading cluster axis over the mesh's ``data`` axis, so every
+        cluster's tables sit on their own device before ``shard_map`` reads
+        them; without one they go to the default device."""
+        import jax
+        import jax.numpy as jnp
+        if mesh is None:
+            put = jnp.asarray
+        else:
+            from jax.sharding import NamedSharding, PartitionSpec
+            sharding = NamedSharding(mesh, PartitionSpec("data"))
+            put = lambda a: jax.device_put(a, sharding)  # noqa: E731
+        return put(self.feats), put(self.neighbors), put(self.weights)
 
     def scatter(self, out) -> np.ndarray:
         """Map per-cluster outputs [K, n_max, D] to global node order.

@@ -42,7 +42,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro import telemetry as tel
 from repro.core.partition import (BucketedPartition, HierPartition,
@@ -187,11 +186,11 @@ def make_decentralized_forward(mesh, cfg, plan: HaloPlan, n_max: int,
         return x[None]
 
     shard = P(axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(P(),) + (shard,) * (3 + len(names)),
         out_specs=shard,
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def forward(params, feats, nbr, wts):
@@ -313,11 +312,11 @@ def make_semi_forward(mesh, cfg, plan: TwoTierPlan,
         return x[None]
 
     shard = P(axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         device_fn, mesh=mesh,
         in_specs=(P(),) + (shard,) * (3 + len(names)),
         out_specs=shard,
-        check_rep=False)
+        check_vma=False)
 
     @jax.jit
     def forward(params, spoke_feats, nbr, wts):

@@ -41,7 +41,7 @@ def _kernel(xq_ref, wq_ref, out_ref, *, in_bits: int, adc_bits: int,
     # digital cross-crossbar accumulation in the same order — as depth=1,
     # so outputs are bit-identical at any depth.
     for t in range(depth):
-        xq = xq_ref[:, t * r:(t + 1) * r]   # [bm, r] uint32 DAC codes
+        xq = xq_ref[:, t * r:(t + 1) * r]   # [bm, r] int32 DAC codes
         wq = wq_ref[t * r:(t + 1) * r, :]   # [r, bn] f32 conductance codes
         acc = jnp.zeros(out_ref.shape, jnp.float32)
         for b in range(in_bits):            # bit-serial DAC cycles
@@ -62,7 +62,8 @@ def crossbar_matmul_quantized(xq: jax.Array, wq: jax.Array,
                               interpret: bool | None = None) -> jax.Array:
     """Bit-serial crossbar matmul on pre-quantized codes.
 
-    xq: [M, K] uint32 input DAC codes (values < 2**in_bits)
+    xq: [M, K] int32 input DAC codes (values < 2**in_bits; the TPU
+        compiler has no uint32 -> float32 cast)
     wq: [K, N] float32 signed conductance codes
     K must be a multiple of cfg.rows_per_xbar; M of bm; N of bn.
     ``depth`` (tuner knob) gives each grid step ``depth`` physical
@@ -73,6 +74,8 @@ def crossbar_matmul_quantized(xq: jax.Array, wq: jax.Array,
     interpret = resolve_interpret(interpret)
     m, k = xq.shape
     k2, n = wq.shape
+    if xq.dtype != jnp.int32:
+        raise ValueError(f"DAC codes must be int32, got {xq.dtype}")
     if k != k2:
         raise ValueError(f"contraction mismatch: xq K={k} vs wq K={k2}")
     for dim, size, mult in (("M", m, bm), ("K", k, cfg.rows_per_xbar),

@@ -51,14 +51,14 @@ class CrossbarNumerics:
 def quantize_inputs(x: jax.Array, cfg: CrossbarNumerics):
     """DAC input quantization: unsigned uniform over [0, max|x|].
 
-    Returns (codes uint32 [.., K], scale f32 scalar). Negative inputs are
+    Returns (codes int32 [.., K], scale f32 scalar). Negative inputs are
     clipped — the paper's cores operate post-ReLU; callers that need signed
     activations split sign digitally (see ``crossbar_matmul_signed``).
     """
     x = x.astype(jnp.float32)   # quantize in f32: fusion-order independent
     x_max = jnp.maximum(jnp.max(jnp.abs(x)), 1e-8)
     scale = x_max / cfg.in_levels
-    codes = jnp.clip(jnp.round(x / scale), 0, cfg.in_levels).astype(jnp.uint32)
+    codes = jnp.clip(jnp.round(x / scale), 0, cfg.in_levels).astype(jnp.int32)
     return codes, scale
 
 
@@ -143,13 +143,18 @@ def crossbar_matmul_ref(x: jax.Array, w: jax.Array,
     return acc * (xs * ws)
 
 
-@partial(jax.jit, static_argnames="cfg")
 def crossbar_matmul_signed_ref(x: jax.Array, w: jax.Array,
                                cfg: CrossbarNumerics = CrossbarNumerics(),
                                w_noise: jax.Array | None = None) -> jax.Array:
     """Signed-activation variant: x is split into positive/negative parts that
     are driven in two passes and recombined digitally (2 DAC passes); one
-    ``w_noise`` draw is shared by both — same programmed arrays."""
+    ``w_noise`` draw is shared by both — same programmed arrays.
+
+    Deliberately not jitted as a whole: called eagerly, the two passes are
+    separate jitted calls and ``pos - neg`` a separate op, exactly as in
+    ``ops.crossbar_matmul_signed``. One jit would let XLA's CPU backend
+    contract the two rescales and the subtraction into a fused multiply-add,
+    which differs from the Pallas path in the last bit."""
     if cfg.ideal:
         return jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
                        preferred_element_type=jnp.float32)

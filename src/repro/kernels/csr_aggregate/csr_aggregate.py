@@ -8,8 +8,10 @@ the gather never materializes an [Nd, S, F] tensor. The destination node's
 accumulator lives in VMEM (the output block is revisited across the S grid
 axis), mirroring the paper's destination-stationary accumulation.
 
-Grid: (node, F // bf, S). Feature rows are fetched in (1, bf) blocks with
-bf a multiple of 128 (VPU lane aligned).
+Grid per call: (node, F // bf, S). Feature rows are fetched in (1, bf)
+blocks with bf a multiple of 128 (VPU lane aligned), out of the ``[N, 1, F]``
+row view; destination rows are chunked across calls so the prefetched
+tables fit SMEM at any node count (``kernels._gather``, DESIGN.md §5).
 """
 from __future__ import annotations
 
@@ -20,10 +22,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels._gather import map_row_chunks, row_block, rows_view
 from repro.kernels._interpret import resolve_interpret
 
 
-def _kernel(nbr_ref, wts_ref, x_ref, out_ref):
+def _kernel(nbr_ref, wts_ref, x_ref, out_ref, *, n_s: int):
     i = pl.program_id(0)
     s = pl.program_id(2)
 
@@ -31,7 +34,7 @@ def _kernel(nbr_ref, wts_ref, x_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    w = wts_ref[i, s]                       # scalar edge weight (SMEM)
+    w = wts_ref[i * n_s + s]                # scalar edge weight (SMEM)
     out_ref[...] += w * x_ref[...].astype(jnp.float32)
 
 
@@ -48,20 +51,27 @@ def csr_aggregate(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
     n, f = x.shape
     nd, s = neighbors.shape
     assert f % bf == 0, (f, bf)
-    grid = (nd, f // bf, s)
+    x_rows = rows_view(x)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,              # neighbors, weights
-        grid=grid,
-        in_specs=[
-            # one neighbor feature row block, steered by the prefetched index
-            pl.BlockSpec((1, bf), lambda i, j, ss, nbr, wts: (nbr[i, ss], j)),
-        ],
-        out_specs=pl.BlockSpec((1, bf), lambda i, j, ss, nbr, wts: (i, j)),
-    )
-    return pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nd, f), jnp.float32),
-        interpret=interpret,
-    )(neighbors, weights.astype(jnp.float32), x)
+    def call(nbr, wts):
+        rows = nbr.shape[0] // s
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,          # neighbors, weights (flat)
+            grid=(rows, f // bf, s),
+            in_specs=[
+                # one neighbor feature row block, steered by the prefetched
+                # index
+                pl.BlockSpec(row_block(bf), lambda i, j, ss, nbr, wts:
+                             (nbr[i * s + ss], 0, j)),
+            ],
+            out_specs=pl.BlockSpec(row_block(bf),
+                                   lambda i, j, ss, nbr, wts: (i, 0, j)),
+        )
+        return pl.pallas_call(
+            functools.partial(_kernel, n_s=s),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((rows, 1, f), jnp.float32),
+            interpret=interpret,
+        )(nbr, wts, x_rows)
+
+    return map_row_chunks(call, neighbors, weights).reshape(nd, f)

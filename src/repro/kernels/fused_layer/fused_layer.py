@@ -30,6 +30,10 @@ held fully resident in VMEM across the whole grid rather than K-tiled by
 BlockSpec; K-tiling for the per-crossbar ADC happens *inside* the kernel on
 the VMEM-resident block, which keeps the reduction-tree position of the ADC
 identical to the standalone ``crossbar_mvm`` kernel.
+
+Rows move through the ``[N, 1, F]`` row view, and destination rows are
+chunked across calls so the flat prefetched tables fit SMEM at any node
+count (``kernels._gather``, DESIGN.md §5).
 """
 from __future__ import annotations
 
@@ -40,6 +44,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels._gather import map_row_chunks, row_block, rows_view
 from repro.kernels._interpret import resolve_interpret
 from repro.kernels.crossbar_mvm.ref import CrossbarNumerics
 
@@ -53,7 +58,7 @@ def _fused_ideal_kernel(nbr_ref, wts_ref, x_ref, w_ref, b_ref, out_ref,
     def _init():
         z_ref[...] = jnp.zeros_like(z_ref)
 
-    w_edge = wts_ref[i, s]                  # scalar edge weight (SMEM)
+    w_edge = wts_ref[i * n_s + s]           # scalar edge weight (SMEM)
     z_ref[...] += w_edge * x_ref[...].astype(jnp.float32)
 
     @pl.when(s == n_s - 1)
@@ -71,14 +76,16 @@ def _fused_zmax_kernel(nbr_ref, wts_ref, x_ref, out_ref, z_ref, *, n_s: int):
     def _init():
         z_ref[...] = jnp.zeros_like(z_ref)
 
-    w_edge = wts_ref[i, s]
+    w_edge = wts_ref[i * n_s + s]
     z_ref[...] += w_edge * x_ref[...].astype(jnp.float32)
 
     @pl.when(s == n_s - 1)
     def _reduce():
         z = z_ref[...]
-        out_ref[0, 0] = jnp.max(jnp.maximum(z, 0.0))
-        out_ref[0, 1] = jnp.max(jnp.maximum(-z, 0.0))
+        pos = jnp.max(jnp.maximum(z, 0.0), axis=1, keepdims=True)
+        neg = jnp.max(jnp.maximum(-z, 0.0), axis=1, keepdims=True)
+        lane = jax.lax.broadcasted_iota(jnp.int32, out_ref.shape, 1)
+        out_ref[...] = jnp.where(lane == 0, pos, neg)
 
 
 def _bit_serial_mvm(codes, wq_ref, cfg: CrossbarNumerics, n_k: int):
@@ -112,7 +119,7 @@ def _fused_quant_kernel(nbr_ref, wts_ref, scales_ref, x_ref, wq_ref, b_ref,
     def _init():
         z_ref[...] = jnp.zeros_like(z_ref)
 
-    w_edge = wts_ref[i, s]
+    w_edge = wts_ref[i * n_s + s]
     z_ref[...] += w_edge * x_ref[...].astype(jnp.float32)
 
     @pl.when(s == n_s - 1)
@@ -132,9 +139,14 @@ def _fused_quant_kernel(nbr_ref, wts_ref, scales_ref, x_ref, wq_ref, b_ref,
         out_ref[...] = jnp.maximum(h, 0.0) if relu else h
 
 
-def _gather_spec(bf: int):
-    # one neighbor feature row, steered by the prefetched index table
-    return pl.BlockSpec((1, bf), lambda i, s, *prefetch: (prefetch[0][i, s], 0))
+def _gather_spec(f: int, n_s: int):
+    # one neighbor feature row, steered by the prefetched flat index table
+    return pl.BlockSpec(row_block(f), lambda i, s, nbr, *_:
+                        (nbr[i * n_s + s], 0, 0))
+
+
+def _row_out_spec(h: int):
+    return pl.BlockSpec(row_block(h), lambda i, s, *_: (i, 0, 0))
 
 
 @functools.partial(jax.jit,
@@ -152,24 +164,31 @@ def fused_ideal_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
     nd, n_s = neighbors.shape
     f2, h = w.shape
     assert f == f2, (x.shape, w.shape)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,              # neighbors, weights
-        grid=(nd, n_s),
-        in_specs=[
-            _gather_spec(f),
-            pl.BlockSpec((f, h), lambda i, s, *_: (0, 0)),    # W resident
-            pl.BlockSpec((1, h), lambda i, s, *_: (0, 0)),    # bias
-        ],
-        out_specs=pl.BlockSpec((1, h), lambda i, s, *_: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((1, f), jnp.float32)],     # z row
-    )
-    return pl.pallas_call(
-        functools.partial(_fused_ideal_kernel, n_s=n_s, relu=relu),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nd, h), jnp.float32),
-        interpret=interpret,
-    )(neighbors, weights.astype(jnp.float32), x,
-      w.astype(jnp.float32), b.astype(jnp.float32).reshape(1, h))
+    x_rows = rows_view(x)
+    w = w.astype(jnp.float32)
+    b = b.astype(jnp.float32).reshape(1, h)
+
+    def call(nbr, wts):
+        rows = nbr.shape[0] // n_s
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,          # neighbors, weights (flat)
+            grid=(rows, n_s),
+            in_specs=[
+                _gather_spec(f, n_s),
+                pl.BlockSpec((f, h), lambda i, s, *_: (0, 0)),  # W resident
+                pl.BlockSpec((1, h), lambda i, s, *_: (0, 0)),  # bias
+            ],
+            out_specs=_row_out_spec(h),
+            scratch_shapes=[pltpu.VMEM((1, f), jnp.float32)],   # z row
+        )
+        return pl.pallas_call(
+            functools.partial(_fused_ideal_kernel, n_s=n_s, relu=relu),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((rows, 1, h), jnp.float32),
+            interpret=interpret,
+        )(nbr, wts, x_rows, w, b)
+
+    return map_row_chunks(call, neighbors, weights).reshape(nd, h)
 
 
 @functools.partial(jax.jit, static_argnames="interpret")
@@ -183,19 +202,25 @@ def fused_zmax(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
     interpret = resolve_interpret(interpret)
     n, f = x.shape
     nd, n_s = neighbors.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(nd, n_s),
-        in_specs=[_gather_spec(f)],
-        out_specs=pl.BlockSpec((1, 2), lambda i, s, *_: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((1, f), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_fused_zmax_kernel, n_s=n_s),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nd, 2), jnp.float32),
-        interpret=interpret,
-    )(neighbors, weights.astype(jnp.float32), x)
+    x_rows = rows_view(x)
+
+    def call(nbr, wts):
+        rows = nbr.shape[0] // n_s
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(rows, n_s),
+            in_specs=[_gather_spec(f, n_s)],
+            out_specs=_row_out_spec(2),
+            scratch_shapes=[pltpu.VMEM((1, f), jnp.float32)],
+        )
+        return pl.pallas_call(
+            functools.partial(_fused_zmax_kernel, n_s=n_s),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((rows, 1, 2), jnp.float32),
+            interpret=interpret,
+        )(nbr, wts, x_rows)
+
+    return map_row_chunks(call, neighbors, weights).reshape(nd, 2)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "relu", "interpret"))
@@ -216,22 +241,30 @@ def fused_quant_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
     f2, h = wq.shape
     assert f == f2 and f % cfg.rows_per_xbar == 0, (x.shape, wq.shape, cfg)
     n_k = f // cfg.rows_per_xbar
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,              # neighbors, weights, scales
-        grid=(nd, n_s),
-        in_specs=[
-            _gather_spec(f),
-            pl.BlockSpec((f, h), lambda i, s, *_: (0, 0)),    # Wq resident
-            pl.BlockSpec((1, h), lambda i, s, *_: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h), lambda i, s, *_: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((1, f), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_fused_quant_kernel, cfg=cfg, n_s=n_s, n_k=n_k,
-                          relu=relu),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nd, h), jnp.float32),
-        interpret=interpret,
-    )(neighbors, weights.astype(jnp.float32), scales.astype(jnp.float32),
-      x, wq.astype(jnp.float32), b.astype(jnp.float32).reshape(1, h))
+    x_rows = rows_view(x)
+    scales = scales.astype(jnp.float32)
+    wq = wq.astype(jnp.float32)
+    b = b.astype(jnp.float32).reshape(1, h)
+
+    def call(nbr, wts):
+        rows = nbr.shape[0] // n_s
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,          # neighbors, weights, scales
+            grid=(rows, n_s),
+            in_specs=[
+                _gather_spec(f, n_s),
+                pl.BlockSpec((f, h), lambda i, s, *_: (0, 0)),  # Wq resident
+                pl.BlockSpec((1, h), lambda i, s, *_: (0, 0)),
+            ],
+            out_specs=_row_out_spec(h),
+            scratch_shapes=[pltpu.VMEM((1, f), jnp.float32)],
+        )
+        return pl.pallas_call(
+            functools.partial(_fused_quant_kernel, cfg=cfg, n_s=n_s,
+                              n_k=n_k, relu=relu),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((rows, 1, h), jnp.float32),
+            interpret=interpret,
+        )(nbr, wts, scales, x_rows, wq, b)
+
+    return map_row_chunks(call, neighbors, weights).reshape(nd, h)

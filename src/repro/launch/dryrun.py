@@ -35,7 +35,7 @@ from repro.configs import ARCHS, SHAPES, cells, get_config
 from repro.distributed.sharding import (activation_rules, batch_shardings,
                                         cache_shardings, optimizer_shardings,
                                         param_shardings)
-from repro.launch.mesh import make_production_mesh, set_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import (batch_struct, make_prefill_step,
                                 make_serve_step, make_train_step)
 from repro.models import build
@@ -71,7 +71,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, remat: str | None = None,
             "params": cfg.param_count(),
             "active_params": cfg.active_param_count()}
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if spec.kind == "train":
             opt_s = _struct(jax.eval_shape(adamw_init, params_s))
             mom_specs = optimizer_shardings(

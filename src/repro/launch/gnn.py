@@ -47,6 +47,7 @@ import numpy as np
 from repro import telemetry as tel
 from repro.core import costmodel, dataset_like, gnn
 from repro.core.partition import ExecutionPlan, plan_execution
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh
 
 
@@ -257,6 +258,7 @@ def main() -> None:
                     help="enable telemetry; export the recorded span trees "
                          "as JSONL to PATH on exit")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.metrics or args.trace:
         tel.enable()
 

@@ -1,32 +1,17 @@
 """Production mesh construction (single-pod 16x16, multi-pod 2x16x16).
 
-``jax.sharding.AxisType`` (and the ``axis_types`` kwarg of ``jax.make_mesh``)
-only exist from jax 0.5.x; on older installs every axis is implicitly Auto,
-so the fallback simply omits the kwarg — semantics are identical.
+Every axis is ``AxisType.Auto``: GSPMD propagates shardings from the
+annotated inputs, and the ambient mesh is installed with ``jax.set_mesh``.
 """
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # older jax: axes are Auto-typed by default
-    _AxisType = None
-
-
-def set_mesh(mesh):
-    """Compat for ``jax.set_mesh`` (jax >= 0.5): on older jax the Mesh object
-    itself is the context manager that installs the ambient mesh."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    if _AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(_AxisType.Auto,) * len(axes))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -27,7 +27,7 @@ from repro.configs import get_config
 from repro.data.tokens import TokenStream
 from repro.distributed.sharding import (activation_rules, batch_shardings,
                                         optimizer_shardings, param_shardings)
-from repro.launch.mesh import make_mesh, set_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import make_train_step
 from repro.models import build
 from repro.optim import AdamWConfig, adamw_init
@@ -110,7 +110,7 @@ def train(cfg: TrainConfig, *, hooks=None) -> dict:
     retries = 0
     step = start
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         while step < cfg.steps:
             try:
                 if "fault" in hooks:

@@ -15,7 +15,7 @@ why benches quarantine the winner under ``timing`` keys.
 """
 from __future__ import annotations
 
-from repro.analysis.roofline import HW, V5E
+from repro.analysis.roofline import HW, V5E, peaks_for
 
 from . import registry
 from .cache import TuneCache
@@ -34,7 +34,17 @@ def current_platform() -> str:
     return f"{base}-interp" if resolve_interpret(None) else base
 
 
-def tune(geom, *, cache: TuneCache | None = None, hw: HW = V5E,
+def target_hw() -> HW:
+    """Peaks the roofline pruning ranks candidates against: the attached
+    TPU's, by ``device_kind`` (an unknown kind raises). Off the TPU the
+    kernels run in the interpreter, which has no peaks of its own; the
+    candidates are the v5e launches, so they are pruned against v5e."""
+    import jax
+    dev = jax.devices()[0]
+    return peaks_for(dev.device_kind) if dev.platform == "tpu" else V5E
+
+
+def tune(geom, *, cache: TuneCache | None = None, hw: HW | None = None,
          seed: int = 0, iters: int = 3, warmup: int = 1,
          slack: float = 2.0, max_survivors: int = 4,
          measure_fn=None, force: bool = False,
@@ -56,7 +66,8 @@ def tune(geom, *, cache: TuneCache | None = None, hw: HW = V5E,
             info["cached"] = True
             return hit, info
 
-    survivors = prune(geom, hw=hw, slack=slack, max_survivors=max_survivors)
+    survivors = prune(geom, hw=hw or target_hw(), slack=slack,
+                      max_survivors=max_survivors)
     info["survivors"] = [(c.as_dict(), b) for c, b in survivors]
     measure_fn = measure_fn or (
         lambda g, c: _real_measure(g, c, seed=seed, iters=iters,

@@ -51,7 +51,7 @@ def crossbar_runner(geom: CrossbarGeometry, config: CrossbarConfig,
                        bm=config.bm, bn=config.bn)
     xq = jnp.asarray(rng.integers(
         0, 2 ** geom.in_bits, size=(grid.m_pad, grid.k_pad)).astype(
-            np.uint32))
+            np.int32))
     wq = jnp.asarray(rng.integers(
         -7, 8, size=(grid.k_pad, grid.n_pad)).astype(np.float32))
 

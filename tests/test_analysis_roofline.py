@@ -145,3 +145,23 @@ def test_instruction_rows_agrees_with_analyze_module():
     text = _compiled_text(f, a, b, c)
     rows = instruction_rows(text)
     assert sum(f for _, f, _, _, _ in rows) == analyze_module(text).flops
+
+
+# ---- peak table keyed by device kind ---------------------------------------
+
+def test_peaks_keyed_by_device_kind_unknown_raises():
+    import pytest
+    from repro.analysis.roofline import PEAKS, peaks_for
+    assert peaks_for("TPU v5 lite") is V5E
+    assert V5E.peak_flops == 197e12 and V5E.hbm_bw == 819e9
+    with pytest.raises(ValueError, match="no peak table"):
+        peaks_for("cpu")
+    assert "cpu" not in PEAKS
+
+
+def test_tuner_prunes_against_v5e_off_the_tpu():
+    """Off the TPU the kernels run interpreted; the tuner ranks the v5e
+    launches against v5e peaks rather than inventing CPU ones."""
+    from repro.tuning.autotune import target_hw
+    assert jax.devices()[0].platform != "tpu"
+    assert target_hw() is V5E

@@ -91,3 +91,31 @@ def test_update_plan_to_different_node_count_swaps_staleness_domain():
     assert out.shape == (24, cfg.out_dim) and srv.refreshes == 2
     with np.testing.assert_raises(IndexError):
         srv.query([g.n_nodes - 1])                     # old-domain id: 39
+
+
+def test_lower_forward_takes_tables_as_arguments():
+    """``lower_forward`` lowers the program ``make_forward`` runs, with the
+    plan's tables as arguments (the chip check reads tpu_custom_call from
+    it) — and that program computes what the server serves."""
+    for setting in ("centralized", "decentralized", "semi"):
+        srv, cfg, g = _server(setting=setting, backend="fused",
+                              n_clusters=None if setting == "centralized"
+                              else 2)
+        srv.refresh()
+        lowered = srv.plan.lower_forward(srv.params, srv.cfg)
+        feats = srv.plan.feats
+        shape = "x".join(str(d) for d in feats.shape)
+        assert f"tensor<{shape}xf32>" in lowered.as_text()
+        out = lowered.compile()(srv.params, *srv.plan.device_inputs()[:3])
+        np.testing.assert_array_equal(srv.plan.scatter(out), srv.embeddings)
+
+
+def test_device_inputs_split_clusters_over_mesh():
+    from jax.sharding import PartitionSpec
+    from repro.launch.mesh import make_mesh
+    srv, _, _ = _server(setting="decentralized", n_clusters=1)
+    mesh = make_mesh((1,), ("data",))
+    for arr in srv.plan.device_inputs(mesh):
+        assert arr.sharding.spec == PartitionSpec("data")
+        assert [s.device for s in arr.addressable_shards] == \
+            list(mesh.devices.flat)

@@ -102,7 +102,7 @@ def test_quantized_kernel_rejects_nondivisible_shapes():
     ok = dict(m=128, k=256, n=128)
 
     def codes(m, k, n):
-        return (jnp.zeros((m, k), jnp.uint32), jnp.zeros((k, n), jnp.float32))
+        return (jnp.zeros((m, k), jnp.int32), jnp.zeros((k, n), jnp.float32))
 
     # each dim individually non-divisible -> named in the error, which
     # also points at the mapper API producing valid shapes
@@ -114,7 +114,7 @@ def test_quantized_kernel_rejects_nondivisible_shapes():
         assert "repro.mapper.tiling.padded_grid" in str(ei.value)
     # mismatched contraction dims
     with pytest.raises(ValueError, match="contraction mismatch"):
-        crossbar_matmul_quantized(jnp.zeros((128, 256), jnp.uint32),
+        crossbar_matmul_quantized(jnp.zeros((128, 256), jnp.int32),
                                   jnp.zeros((128, 128), jnp.float32),
                                   cfg, interpret=True)
     # the ops-layer wrapper maps the same shapes fine (mapper padding)

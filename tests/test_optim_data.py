@@ -76,15 +76,14 @@ def test_error_feedback_unbiased_over_steps():
 
 
 def test_compressed_psum_matches_mean():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_mesh
     mesh = make_mesh((1,), ("data",))
     g = jnp.linspace(-1, 1, 32).reshape(4, 8)
     r = jnp.zeros_like(g)
-    fn = shard_map(lambda g, r: compressed_psum(g, r, "data"), mesh=mesh,
-                   in_specs=(P(), P()), out_specs=(P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(lambda g, r: compressed_psum(g, r, "data"),
+                       mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
+                       check_vma=False)
     mean, _ = fn(g, r)
     np.testing.assert_allclose(np.asarray(mean), np.asarray(g), atol=0.02)
 

@@ -230,7 +230,7 @@ def test_crossbar_depth_must_divide_crossbar_count():
     from repro.kernels.crossbar_mvm.crossbar_mvm import \
         crossbar_matmul_quantized
 
-    xq = jnp.zeros((8, 256), jnp.uint32)
+    xq = jnp.zeros((8, 256), jnp.int32)
     wq = jnp.zeros((256, 128), jnp.float32)
     with pytest.raises(ValueError, match="depth 3 must divide"):
         crossbar_matmul_quantized(xq, wq, CrossbarNumerics(rows_per_xbar=128),
