@@ -24,6 +24,8 @@ import dataclasses
 
 import numpy as np
 
+from repro import telemetry as tel
+
 from .graph import Graph, GraphStats
 
 
@@ -684,25 +686,31 @@ class ExecutionPlan:
         """Map per-cluster outputs [K, n_max, D] to global node order.
 
         Bucketed plans pass the forward's tuple of per-bucket
-        ``[K_b, n_cap, D]`` arrays."""
-        if self.bucketed is not None and isinstance(out, (list, tuple)):
-            parts = [np.asarray(o) for o in out]
-            full = np.zeros((self.graph.n_nodes, parts[0].shape[-1]),
-                            parts[0].dtype)
-            sizes = self.part.local_mask.sum(axis=1)
-            for b, cl in enumerate(self.bucketed.clusters):
-                for j, c in enumerate(cl):
-                    m = int(sizes[c])
-                    full[self.part.local_nodes[c, :m]] = parts[b][j, :m]
+        ``[K_b, n_cap, D]`` arrays. Spans: ``plan.scatter``, and inside it
+        ``plan.scatter.fetch``, the copy from the device to the host; the
+        rest is the assembly in global order."""
+        with tel.span("plan.scatter"):
+            bucketed = (self.bucketed is not None
+                        and isinstance(out, (list, tuple)))
+            with tel.span("plan.scatter.fetch"):
+                out = ([np.asarray(o) for o in out] if bucketed
+                       else np.asarray(out))
+            if bucketed:
+                full = np.zeros((self.graph.n_nodes, out[0].shape[-1]),
+                                out[0].dtype)
+                sizes = self.part.local_mask.sum(axis=1)
+                for b, cl in enumerate(self.bucketed.clusters):
+                    for j, c in enumerate(cl):
+                        m = int(sizes[c])
+                        full[self.part.local_nodes[c, :m]] = out[b][j, :m]
+                return full
+            if self.setting == "centralized":
+                return out[0]
+            full = np.zeros((self.graph.n_nodes, out.shape[-1]), out.dtype)
+            for c in range(self.n_clusters):
+                m = self.part.local_mask[c]
+                full[self.part.local_nodes[c][m]] = out[c][m]
             return full
-        out = np.asarray(out)
-        if self.setting == "centralized":
-            return out[0]
-        full = np.zeros((self.graph.n_nodes, out.shape[-1]), out.dtype)
-        for c in range(self.n_clusters):
-            m = self.part.local_mask[c]
-            full[self.part.local_nodes[c][m]] = out[c][m]
-        return full
 
     def layout_stats(self, cfg=None) -> dict:
         """Deterministic padded-layout accounting for this plan.

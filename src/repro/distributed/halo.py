@@ -32,6 +32,11 @@ paths run aggregation then the feature transform, ``fused`` runs both stages
 in one ``fused_gnn_layer`` kernel launch with Z resident in VMEM (so the
 decentralized and semi-decentralized settings get the same HBM-traffic win
 as the centralized path — DESIGN.md §5).
+
+The bucketed host loops open ``halo.gather`` and ``halo.mvm`` spans per
+layer and bucket (``halo.tier0_gather`` for the semi tier 0). They time
+dispatch and never wait for the device; inside a JAX profile they land on
+the host timeline beside the device trace (DESIGN.md §14).
 """
 from __future__ import annotations
 
@@ -433,9 +438,9 @@ def make_emulated_bucketed_forward(cfg, bplan: BucketedHaloPlan,
 
     def forward(params, feats, nbrs, wtss):
         # Spans here time *dispatch* (the loop body runs ahead of the
-        # device); telemetry.device_sync closes each layer only when
-        # tracing is enabled, so the overlap schedule is untouched when
-        # telemetry is off.  Disabled spans are shared no-op singletons.
+        # device) and never wait for it, so the overlap schedule is the
+        # same with telemetry on or off; the device's time per bucket is
+        # in the device trace of a profile, on the spans' clock.
         tracer = tel.get_tracer()
         xs = list(feats)
         n_layers = len(params)
@@ -463,7 +468,6 @@ def make_emulated_bucketed_forward(cfg, bplan: BucketedHaloPlan,
                         xs[b] = _bucket_layer(xs[b], halo, nbrs[b], wtss[b],
                                               layer["w"], layer["b"],
                                               cfg=cfg, act=act)
-            tracer.device_sync(xs, name="halo.layer_sync")
         return tuple(xs)
 
     return forward

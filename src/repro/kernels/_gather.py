@@ -17,6 +17,10 @@ the TPU compiler shape how they do it (DESIGN.md §5):
 
 Each destination row is computed by the same ops in the same order in
 every chunk, so chunking does not change a single output bit.
+
+Every launch names its kernel and states its grid (``gather_metadata``):
+both reach the profiler trace, so each launch's event there says which
+kernel ran and how many grid steps it took.
 """
 from __future__ import annotations
 
@@ -35,6 +39,18 @@ def rows_view(a: jax.Array) -> jax.Array:
 def row_block(width: int) -> tuple:
     """Block shape of one ``width``-lane row of a ``rows_view`` array."""
     return (pl.Squeezed(), 1, width)
+
+
+def gather_metadata(kernel: str, rows: int, slots: int, f_in: int,
+                    f_out: int, **grid: int) -> dict:
+    """``pallas_call(metadata=...)`` of one gather launch: the kernel's
+    name, its chunk's ``rows`` x ``slots`` grid, the widths it reads and
+    writes, and any further grid dimension, all as strings. The compiled
+    custom call carries it as ``kernel_metadata``, and so does its event in
+    a profile."""
+    meta = dict(kernel=kernel, rows=rows, slots=slots, f_in=f_in,
+                f_out=f_out, **grid)
+    return {k: str(v) for k, v in meta.items()}
 
 
 def map_row_chunks(call, neighbors: jax.Array, weights: jax.Array):

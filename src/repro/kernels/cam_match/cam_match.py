@@ -66,6 +66,8 @@ def cam_search(ci: jax.Array, queries: jax.Array, bq: int = 8, be: int = 128,
             jax.ShapeDtypeStruct((q, e), jnp.int8),
             jax.ShapeDtypeStruct((q, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=interpret, name="cam_search",
+        metadata={"kernel": "cam_search", "query_blocks": str(grid[0]),
+                  "entry_blocks": str(grid[1])},
     )(ci.reshape(1, e), queries.reshape(q, 1))
     return match, counts

@@ -107,5 +107,8 @@ def crossbar_matmul_quantized(xq: jax.Array, wq: jax.Array,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
-        interpret=interpret,
+        interpret=interpret, name="crossbar_matmul_quantized",
+        metadata={"kernel": "crossbar_matmul_quantized",
+                  "m_blocks": str(grid[0]), "n_blocks": str(grid[1]),
+                  "k_blocks": str(grid[2])},
     )(xq, wq)

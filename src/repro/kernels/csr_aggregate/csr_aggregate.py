@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._gather import map_row_chunks, row_block, rows_view
+from repro.kernels._gather import (gather_metadata, map_row_chunks,
+                                  row_block, rows_view)
 from repro.kernels._interpret import resolve_interpret
 
 
@@ -71,7 +72,9 @@ def csr_aggregate(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
             functools.partial(_kernel, n_s=s),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((rows, 1, f), jnp.float32),
-            interpret=interpret,
+            interpret=interpret, name="csr_aggregate",
+            metadata=gather_metadata("csr_aggregate", rows, s, f, f,
+                                     f_blocks=f // bf),
         )(nbr, wts, x_rows)
 
     return map_row_chunks(call, neighbors, weights).reshape(nd, f)

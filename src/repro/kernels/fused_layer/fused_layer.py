@@ -44,7 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._gather import map_row_chunks, row_block, rows_view
+from repro.kernels._gather import (gather_metadata, map_row_chunks,
+                                  row_block, rows_view)
 from repro.kernels._interpret import resolve_interpret
 from repro.kernels.crossbar_mvm.ref import CrossbarNumerics
 
@@ -185,7 +186,8 @@ def fused_ideal_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
             functools.partial(_fused_ideal_kernel, n_s=n_s, relu=relu),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((rows, 1, h), jnp.float32),
-            interpret=interpret,
+            interpret=interpret, name="fused_ideal_layer",
+            metadata=gather_metadata("fused_ideal_layer", rows, n_s, f, h),
         )(nbr, wts, x_rows, w, b)
 
     return map_row_chunks(call, neighbors, weights).reshape(nd, h)
@@ -217,7 +219,8 @@ def fused_zmax(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
             functools.partial(_fused_zmax_kernel, n_s=n_s),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((rows, 1, 2), jnp.float32),
-            interpret=interpret,
+            interpret=interpret, name="fused_zmax",
+            metadata=gather_metadata("fused_zmax", rows, n_s, f, 2),
         )(nbr, wts, x_rows)
 
     return map_row_chunks(call, neighbors, weights).reshape(nd, 2)
@@ -264,7 +267,8 @@ def fused_quant_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
                               n_k=n_k, relu=relu),
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((rows, 1, h), jnp.float32),
-            interpret=interpret,
+            interpret=interpret, name="fused_quant_layer",
+            metadata=gather_metadata("fused_quant_layer", rows, n_s, f, h),
         )(nbr, wts, scales, x_rows, wq, b)
 
     return map_row_chunks(call, neighbors, weights).reshape(nd, h)

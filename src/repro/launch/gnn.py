@@ -96,13 +96,22 @@ class GNNServer:
         return self.embeddings is None or self._served_version != self.version
 
     def refresh(self) -> float:
-        """Recompute all node embeddings; returns wall-clock seconds."""
+        """Recompute all node embeddings; returns wall-clock seconds.
+
+        Spans: ``server.refresh`` holds ``server.refresh.dispatch`` (the
+        forward built on first use, then called up to its return),
+        ``server.refresh.wait`` (until the device is done) and
+        ``plan.scatter`` (the fetch to the host and the assembly in global
+        order)."""
         t0 = time.perf_counter()
         with tel.span("server.refresh", setting=self.plan.setting):
-            if self._forward is None:
-                self._forward = self.plan.make_forward(
-                    self.cfg, mesh=self._mesh, mode=self.mode)
-            out = jax.block_until_ready(self._forward(self.params))
+            with tel.span("server.refresh.dispatch"):
+                if self._forward is None:
+                    self._forward = self.plan.make_forward(
+                        self.cfg, mesh=self._mesh, mode=self.mode)
+                out = self._forward(self.params)
+            with tel.span("server.refresh.wait"):
+                out = jax.block_until_ready(out)
             # bucketed plans return a tuple of ragged per-bucket tables;
             # scatter handles both shapes (np.asarray would choke on a tuple)
             self.embeddings = self.plan.scatter(out)

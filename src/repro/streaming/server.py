@@ -166,18 +166,22 @@ class StreamingGNNServer(GNNServer):
                 # cold start or params/plan moved: every cache level is
                 # invalid
                 eng.params = self.params
-                upd = eng.commit_full(self._pending)
+                with tel.span("engine.commit_full"):
+                    upd = eng.commit_full(self._pending)
                 self.full_refreshes += 1
             else:
-                upd = eng.apply_delta(self._pending)
+                with tel.span("engine.apply_delta"):
+                    upd = eng.apply_delta(self._pending)
                 if upd.full:
                     self.full_refreshes += 1
             sp.set(full=upd.full)
             tel.record_commit(upd, self.plan.setting)
             self._pending_ticks = 0
             self._pending_dirty[:] = False
-            self._live_feats = eng.graph.features.copy()
-            self.embeddings = eng.embeddings()
+            with tel.span("server.commit.live_copy"):
+                self._live_feats = eng.graph.features.copy()
+            with tel.span("server.commit.embeddings"):
+                self.embeddings = eng.embeddings()
         self.commits += 1
         self.refreshes += 1
         self._served_version = self.version

@@ -11,7 +11,10 @@ instrument unconditionally —
 — and pay one flag check per call when telemetry is off (``span`` returns a
 shared null singleton; metric mutations no-op).  ``enable()`` turns on span
 trees, span-duration histograms (``span_seconds{span=...}``), counters,
-gauges, audit events, and the ``device_sync`` billing points.
+gauges and audit events.  Whether telemetry is on or off, while a JAX
+profile is being captured every span also writes its name into the
+profile as a ``jax.profiler.TraceAnnotation``, so the program's phases sit
+on the same clock as the device trace; no flag turns that on.
 
 Exporters: ``export_metrics(path)`` (JSONL), ``export_trace(path)``
 (JSONL span trees), ``prometheus_text()``.  ``snapshot()`` returns the
@@ -46,16 +49,14 @@ def enabled() -> bool:
     return _TRACER.enabled
 
 
-def enable(xla_annotations: bool = False) -> None:
-    """Turn telemetry on process-wide (spans, metrics, sync points)."""
+def enable() -> None:
+    """Turn telemetry on process-wide (span trees and metrics)."""
     _TRACER.enabled = True
-    _TRACER.xla_annotations = bool(xla_annotations)
     _REGISTRY.enabled = True
 
 
 def disable() -> None:
     _TRACER.enabled = False
-    _TRACER.xla_annotations = False
     _REGISTRY.enabled = False
 
 
@@ -69,10 +70,6 @@ def reset() -> None:
 
 def span(name: str, **attrs: Any):
     return _TRACER.span(name, **attrs)
-
-
-def device_sync(x: Any, name: str = "device_sync") -> Any:
-    return _TRACER.device_sync(x, name=name)
 
 
 def counter(name: str, **labels: Any) -> Counter:
@@ -124,7 +121,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "default_buckets",
     "CommitSample", "DriftLedger", "commit_sample",
     "get_tracer", "get_registry", "enabled", "enable", "disable", "reset",
-    "span", "device_sync", "counter", "gauge", "histogram", "event",
+    "span", "counter", "gauge", "histogram", "event",
     "snapshot", "export_metrics", "export_trace", "prometheus_text",
     "instrument_forward", "record_commit", "record_streaming_traffic",
 ]

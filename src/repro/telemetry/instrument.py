@@ -6,8 +6,9 @@ would fire once, at trace time), so it does three things at the Python
 boundary instead:
 
   1. opens a ``plan.forward`` root span tagged with the plan's
-     setting/backend/clusters and closes it only after ``device_sync`` —
-     async dispatch is billed to the span that caused it;
+     setting/backend/clusters; it closes when the forward returns, so it
+     times dispatch and never waits for the device (the device's time is
+     in the device trace of a profile, on the span's clock);
   2. bills wire bytes onto zero-duration *accounting spans* computed from
      the plan's own ``measured_traffic`` report — the same executed
      send/recv tables ``distributed.halo`` hands to the exchange.  Span-tree
@@ -58,7 +59,6 @@ def instrument_forward(plan, cfg, mode: str, fwd: Callable) -> Callable:
             if total:
                 get_registry().counter("halo.shipped_bytes",
                                        setting=plan.setting).inc(total)
-            tracer.device_sync(out, name="plan.forward.sync")
         return out
 
     return run

@@ -7,17 +7,21 @@ while a per-name aggregate (count/total/max) survives ring eviction.
 
 Design constraints (the ≤5% overhead contract of benchmarks/obs_overhead.py):
 
-- When the tracer is disabled, ``span()`` returns a shared immutable
-  ``_NULL_SPAN`` singleton whose enter/exit/set/add_bytes are no-ops — the
-  disabled cost of an instrumented call site is one attribute load and one
+- When the tracer is disabled and no profile is being captured, ``span()``
+  returns a shared immutable ``NULL_SPAN`` singleton whose
+  enter/exit/set/add_bytes are no-ops — the disabled cost of an
+  instrumented call site is one flag check, one profiler check and one
   method call, no allocation.
-- Spans never force device synchronisation by themselves.  JAX dispatch is
-  async, so a span around a jitted call measures *dispatch* time only; call
-  sites that want execution billed to a span use ``tracer.device_sync(x)``,
-  which blocks inside a dedicated child span — and only when tracing is
-  enabled, so disabling telemetry also removes the sync points.
-- With ``xla_annotations=True`` each span also enters a
-  ``jax.profiler.TraceAnnotation`` so spans land in XLA/perfetto profiles.
+- While a JAX profile is being captured
+  (``jax.profiler.TraceAnnotation.is_enabled()``), every span writes a
+  ``TraceAnnotation`` of its name into the profile, enabled or not: the
+  program's spans then sit on the host timeline of any profile, on the
+  device trace's clock. A disabled tracer returns a ``_ProfileSpan`` that
+  does only that (its ``set``/``add_bytes`` are no-ops).
+- Spans never force device synchronisation. JAX dispatch is async, so a
+  span around a jitted call measures *dispatch* time only; the device's
+  time is read from the device trace of a profile, which shares the
+  spans' clock.
 
 Bytes accounting: ``Span.add_bytes`` attaches wire bytes to a span and
 ``Span.total_bytes()`` sums a subtree.  The instrumentation layer
@@ -33,7 +37,11 @@ import time
 from collections import deque
 from typing import Any, Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Span", "SpanTracer", "NULL_SPAN"]
+
+_profiling = TraceAnnotation.is_enabled     # a profile is being captured
 
 
 class Span:
@@ -94,24 +102,17 @@ class Span:
             if tr._stack:
                 tr._stack[-1].children.append(self)
             tr._stack.append(self)
-            if tr.xla_annotations:
-                try:  # pragma: no cover - exercised only under a profiler
-                    from jax.profiler import TraceAnnotation
-
-                    self._ann = TraceAnnotation(self.name)
-                    self._ann.__enter__()
-                except Exception:
-                    self._ann = None
+        if _profiling():
+            self._ann = TraceAnnotation(self.name)
+            self._ann.__enter__()
         self.t_start = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.t_end = time.perf_counter()
         if self._ann is not None:
-            try:  # pragma: no cover
-                self._ann.__exit__(exc_type, exc, tb)
-            finally:
-                self._ann = None
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         tr = self._tracer
         if tr is not None:
             tr._close(self)
@@ -145,18 +146,33 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _ProfileSpan(_NullSpan):
+    """A disabled tracer's span while a profile is captured: its name in
+    the profile, nothing recorded."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str):
+        self._ann = TraceAnnotation(name)
+
+    def __enter__(self) -> "_ProfileSpan":
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
 class SpanTracer:
     """Produces span trees; keeps a bounded ring of completed root spans.
 
     Parameters
     ----------
     enabled:
-        When False (default) ``span()`` returns ``NULL_SPAN`` and
-        ``device_sync`` is an identity — the instrumented hot paths pay
-        only a flag check.
-    xla_annotations:
-        Mirror every span into ``jax.profiler.TraceAnnotation`` so spans
-        show up in XLA device profiles.
+        When False (default) ``span()`` returns ``NULL_SPAN`` outside a
+        profile and a ``_ProfileSpan`` inside one — the instrumented hot
+        paths pay only a flag check and a profiler check.
     max_roots:
         Ring-buffer capacity for completed top-level span trees.
     registry:
@@ -165,10 +181,9 @@ class SpanTracer:
         span name fall out of tracing with no second instrumentation pass.
     """
 
-    def __init__(self, enabled: bool = False, xla_annotations: bool = False,
-                 max_roots: int = 256, registry: Any = None):
+    def __init__(self, enabled: bool = False, max_roots: int = 256,
+                 registry: Any = None):
         self.enabled = bool(enabled)
-        self.xla_annotations = bool(xla_annotations)
         self.registry = registry
         self.roots: deque = deque(maxlen=int(max_roots))
         self._stack: List[Span] = []
@@ -177,9 +192,9 @@ class SpanTracer:
 
     # -- span creation ---------------------------------------------------
     def span(self, name: str, **attrs: Any):
-        if not self.enabled:
-            return NULL_SPAN
-        return Span(name, tracer=self, attrs=attrs or None)
+        if self.enabled:
+            return Span(name, tracer=self, attrs=attrs or None)
+        return _ProfileSpan(name) if _profiling() else NULL_SPAN
 
     def current(self) -> Optional[Span]:
         return self._stack[-1] if self._stack else None
@@ -206,22 +221,6 @@ class SpanTracer:
         reg = self.registry
         if reg is not None:
             reg.histogram("span_seconds", span=sp.name).observe(dur)
-
-    # -- device sync -------------------------------------------------------
-    def device_sync(self, x: Any, name: str = "device_sync") -> Any:
-        """Block until ``x`` (any pytree of arrays) is ready, inside a span.
-
-        JAX dispatch is async: without an explicit sync, device time leaks
-        out of the span that dispatched it.  No-op pass-through when the
-        tracer is disabled, so disabling telemetry also removes the
-        serialization points.
-        """
-        if not self.enabled:
-            return x
-        import jax
-
-        with self.span(name):
-            return jax.block_until_ready(x)
 
     # -- reporting ---------------------------------------------------------
     def summary(self) -> Dict[str, Dict[str, float]]:

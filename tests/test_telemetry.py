@@ -70,11 +70,30 @@ def test_disabled_tracer_returns_shared_null_span():
     assert not tr.roots and tr.summary() == {}
 
 
-def test_disabled_device_sync_is_identity():
-    tr = SpanTracer(enabled=False)
-    x = object()
-    assert tr.device_sync(x) is x
-    assert not tr.roots
+def test_disabled_span_outside_a_profile_is_null_span():
+    import jax
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert tel.span("server.refresh", setting="centralized") is NULL_SPAN
+    assert SpanTracer(enabled=False).span("plan.scatter") is NULL_SPAN
+
+
+def test_spans_reach_a_profile_with_telemetry_off_or_on(tmp_path):
+    """Inside a profile a disabled span writes only its name there (its
+    set/add_bytes are no-ops, nothing is recorded); an enabled one still
+    builds its tree."""
+    import jax
+    off, on = SpanTracer(enabled=False), SpanTracer(enabled=True)
+    with jax.profiler.trace(str(tmp_path)):
+        assert jax.profiler.TraceAnnotation.is_enabled()
+        s = off.span("server.query", k=1)
+        assert s is not NULL_SPAN
+        with s as inner:
+            assert inner.set(a=1).add_bytes(5) is inner
+        with on.span("tick") as t:
+            t.add_bytes(3)
+    assert off.span("server.query") is NULL_SPAN
+    assert not off.roots and off.summary() == {}
+    assert on.roots[0].name == "tick" and on.roots[0].total_bytes() == 3
 
 
 def test_disabled_registry_mutations_do_not_register():

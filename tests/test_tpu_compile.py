@@ -6,12 +6,15 @@ is described, not attached (``jax.experimental.topologies``), with
 the TPU tiling refuses, SMEM or VMEM overruns, and dtypes Mosaic cannot
 cast. Shapes are the collab (Table 2, F=496) and taxi (§4.2, F=216) widths,
 at destination-row counts above the 16,384 rows that single-call SMEM
-tables overran, with S=8.
+tables overran, with S=8. Every launch is also checked for its name and
+its ``kernel_metadata``, which a profile's trace event of it carries.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time.
 """
+import json
 import os
+import re
 
 import pytest
 import jax
@@ -96,3 +99,64 @@ def test_cam_search_compiles(one_chip):
         lambda ci, q: search(ci, q, backend="pallas", interpret=False),
         one_chip, ((65_536,), jnp.int32), ((1024,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def _launch(kernel, **grid):
+    return kernel, {"kernel": kernel, **{k: str(v) for k, v in grid.items()}}
+
+
+# (kernel, the metadata its launch states) at small shapes: the fused and
+# csr kernels gather chunks of 4,096 rows x 8 slots, F=216 padded to 128
+# lanes (to a whole crossbar, 512 rows, on the bit-accurate path)
+LAUNCHES = {
+    "fused_ideal_layer": [_launch("fused_ideal_layer", rows=4096, slots=S,
+                                  f_in=256, f_out=128)],
+    "fused_quant_layer": [_launch("fused_zmax", rows=4096, slots=S,
+                                  f_in=512, f_out=2),
+                          _launch("fused_quant_layer", rows=4096, slots=S,
+                                  f_in=512, f_out=128)],
+    "csr_aggregate": [_launch("csr_aggregate", rows=4096, slots=S, f_in=256,
+                              f_out=256, f_blocks=2)],
+    "crossbar_matmul_quantized": [_launch("crossbar_matmul_quantized",
+                                          m_blocks=4, n_blocks=1,
+                                          k_blocks=1)],
+    "cam_search": [_launch("cam_search", query_blocks=16,
+                           entry_blocks=64)],
+}
+
+
+def _compile_small(name, sharding):
+    n, f, h = 10_000, 216, 64
+    if name in ("fused_ideal_layer", "fused_quant_layer"):
+        cfg = CrossbarNumerics(ideal=name == "fused_ideal_layer")
+        return _compiled_text(
+            lambda x, nbr, wts, w, b: fused_gnn_layer(
+                x, nbr, wts, w, b, cfg, relu=True, bf=128, interpret=False),
+            sharding, *_layer_shapes(n, n, f, h))
+    if name == "csr_aggregate":
+        return _compiled_text(
+            lambda x, nbr, wts: aggregate(x, nbr, wts, backend="pallas",
+                                          bf=128, interpret=False),
+            sharding, *_layer_shapes(n, n, f, h)[:3])
+    if name == "crossbar_matmul_quantized":
+        return _compiled_text(
+            lambda x, w: crossbar_matmul(x, w, CrossbarNumerics(),
+                                         interpret=False),
+            sharding, ((512, f), jnp.float32), ((f, h), jnp.float32))
+    return _compiled_text(
+        lambda ci, q: search(ci, q, backend="pallas", interpret=False),
+        sharding, ((8192,), jnp.int32), ((128,), jnp.int32))
+
+
+@pytest.mark.parametrize("name", list(LAUNCHES))
+def test_launches_carry_their_name_and_metadata(one_chip, name):
+    """Every launch is an instruction named after its kernel, and its
+    ``kernel_metadata`` states the kernel and its grid: the trace event of
+    a launch is that instruction's text."""
+    text = _compile_small(name, one_chip)
+    found = {}
+    for m in re.finditer(r'%([\w.]+) = [^\n]*custom_call_target='
+                         r'"tpu_custom_call"[^\n]*kernel_metadata=(\{.*?\})',
+                         text, re.DOTALL):
+        found[m.group(1).rsplit(".", 1)[0]] = json.loads(m.group(2))
+    assert found == dict(LAUNCHES[name])
