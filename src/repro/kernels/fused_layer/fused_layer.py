@@ -6,17 +6,30 @@ feature extraction ``H = act(Z @ W + b)`` on the MVM crossbar core — the
 intermediate ``Z`` never leaves the accelerator. The composed TPU path
 (``csr_aggregate`` then ``crossbar_mvm``) loses exactly that property: ``Z``
 makes a full HBM round-trip between the two kernels. Here both stages share
-one grid step, so the destination node's accumulator row is handed to the
-MXU matmul while still resident in VMEM (DESIGN.md §5):
+one kernel, so the accumulated rows are handed to the MXU matmul while
+still resident in VMEM (DESIGN.md §5).
+
+``_fused_ideal_kernel`` (float32 feature extraction, ideal numerics)
+gathers a block of ``R`` destination rows per grid step. The feature table
+stays in HBM; the rows of block ``j + 1`` are copied by hand, one DMA per
+(row, slot), into one of two VMEM buffers ``[S, R, 1, F]`` while block
+``j`` is reduced from the other:
+
+  grid (block j of R rows):
+    j == 0    : start DMAs of block 0   -> buf[0]
+    j + 1 < J : start DMAs of block j+1 -> buf[(j+1) % 2]   buf[., s, r] =
+                                                         X[nbr[jR + r, s]]
+    every j   : wait buf[j % 2]
+                z[R, F]  = sum_s w[:, s] * buf[j % 2, s]   (slot order, f32)
+                out[R, H] = act(z @ W + b)                 (MXU, Z in VMEM)
+
+The bit-accurate path's two kernels gather one row-slot per grid step:
 
   grid (node i, sample s):
     s == 0     : z_acc[1, F]  = 0                  (VMEM scratch)
     every s    : z_acc       += w[i,s] * X[nbr[i,s]]   (scalar-prefetch gather)
-    s == S - 1 : out[i]       = act(z_acc @ W + b)     (MXU, Z stays in VMEM)
+    s == S - 1 : emit from z_acc
 
-Three kernels share the gather loop:
-
-  * ``_fused_ideal_kernel``  — float32 feature extraction (ideal numerics).
   * ``_fused_zmax_kernel``   — emits only per-node (max(z,0), max(-z,0));
     the bit-accurate path needs the *global* DAC scale of Z before it can
     quantize, and this pass provides it without materializing Z in HBM
@@ -44,29 +57,48 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._gather import (gather_metadata, map_row_chunks,
-                                  row_block, rows_view)
+from repro.kernels._gather import (block_rows, gather_metadata,
+                                  map_row_chunks, row_block, rows_view)
 from repro.kernels._interpret import resolve_interpret
 from repro.kernels.crossbar_mvm.ref import CrossbarNumerics
 
 
-def _fused_ideal_kernel(nbr_ref, wts_ref, x_ref, w_ref, b_ref, out_ref,
-                        z_ref, *, n_s: int, relu: bool):
-    i = pl.program_id(0)
-    s = pl.program_id(1)
+def _fused_ideal_kernel(nbr_ref, x_hbm, wts_ref, w_ref, b_ref, out_ref,
+                        buf, sem, *, relu: bool):
+    j = pl.program_id(0)
+    n_s, block, _, f = buf.shape[1:]
 
-    @pl.when(s == 0)
-    def _init():
-        z_ref[...] = jnp.zeros_like(z_ref)
+    def fetch(blk, slot):
+        # one DMA per (row, slot) of block ``blk``, all on ``sem[slot]``
+        base = blk * block * n_s
 
-    w_edge = wts_ref[i * n_s + s]           # scalar edge weight (SMEM)
-    z_ref[...] += w_edge * x_ref[...].astype(jnp.float32)
+        def row(r, carry):
+            for s in range(n_s):
+                pltpu.make_async_copy(x_hbm.at[nbr_ref[base + r * n_s + s]],
+                                      buf.at[slot, s, r],
+                                      sem.at[slot]).start()
+            return carry
 
-    @pl.when(s == n_s - 1)
-    def _transform():
-        h = jnp.dot(z_ref[...], w_ref[...],
-                    preferred_element_type=jnp.float32) + b_ref[...]
-        out_ref[...] = jnp.maximum(h, 0.0) if relu else h
+        jax.lax.fori_loop(0, block, row, 0)
+
+    @pl.when(j == 0)
+    def _first():
+        fetch(0, 0)
+
+    @pl.when(j + 1 < pl.num_programs(0))
+    def _next():
+        fetch(j + 1, (j + 1) % 2)
+
+    slot = j % 2
+    # one wait for the whole buffer: the row DMAs into it add up to its size
+    pltpu.make_async_copy(buf.at[slot], buf.at[slot], sem.at[slot]).wait()
+    wts = wts_ref[...]
+    z = jnp.zeros((block, f), jnp.float32)
+    for s in range(n_s):
+        z += wts[:, s:s + 1] * buf[slot, s].reshape(block, f).astype(
+            jnp.float32)
+    h = jnp.dot(z, w_ref[...], preferred_element_type=jnp.float32) + b_ref[...]
+    out_ref[...] = jnp.maximum(h, 0.0) if relu else h
 
 
 def _fused_zmax_kernel(nbr_ref, wts_ref, x_ref, out_ref, z_ref, *, n_s: int):
@@ -158,11 +190,13 @@ def fused_ideal_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
     """act((A_hat @ X) @ W + b) in one kernel, ideal float numerics.
 
     x: [N, F]; neighbors/weights: [Nd, S]; w: [F, H]; b: [H].
-    Returns [Nd, H] float32. Z never touches HBM.
+    Returns [Nd, H] float32. Z never touches HBM. Each chunk of
+    destination rows runs as ``block_rows`` blocks, zero-weight rows
+    padding the last.
     """
     interpret = resolve_interpret(interpret)
     n, f = x.shape
-    nd, n_s = neighbors.shape
+    n_s = neighbors.shape[1]
     f2, h = w.shape
     assert f == f2, (x.shape, w.shape)
     x_rows = rows_view(x)
@@ -171,26 +205,42 @@ def fused_ideal_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
 
     def call(nbr, wts):
         rows = nbr.shape[0] // n_s
+        block = block_rows(rows, n_s, f)
+        padded = -(-rows // block) * block  # weight-0 rows, dropped below
+        pad = (0, (padded - rows) * n_s)
+        nbr, wts = jnp.pad(nbr, pad), jnp.pad(wts, pad)
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,          # neighbors, weights (flat)
-            grid=(rows, n_s),
+            num_scalar_prefetch=1,          # neighbors (flat)
+            grid=(padded // block,),
             in_specs=[
-                _gather_spec(f, n_s),
-                pl.BlockSpec((f, h), lambda i, s, *_: (0, 0)),  # W resident
-                pl.BlockSpec((1, h), lambda i, s, *_: (0, 0)),  # bias
+                pl.BlockSpec(memory_space=pl.ANY),            # X in HBM
+                pl.BlockSpec((block, n_s), lambda j, *_: (j, 0)),
+                pl.BlockSpec((f, h), lambda j, *_: (0, 0)),   # W resident
+                pl.BlockSpec((1, h), lambda j, *_: (0, 0)),   # bias
             ],
-            out_specs=_row_out_spec(h),
-            scratch_shapes=[pltpu.VMEM((1, f), jnp.float32)],   # z row
+            out_specs=pl.BlockSpec((block, h), lambda j, *_: (j, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, n_s, block, 1, f), x.dtype),  # gathered rows
+                pltpu.SemaphoreType.DMA((2,)),
+            ],
         )
-        return pl.pallas_call(
-            functools.partial(_fused_ideal_kernel, n_s=n_s, relu=relu),
+        out = pl.pallas_call(
+            functools.partial(_fused_ideal_kernel, relu=relu),
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((rows, 1, h), jnp.float32),
+            out_shape=jax.ShapeDtypeStruct((padded, h), jnp.float32),
+            # step j starts the copies of step j + 1
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
             interpret=interpret, name="fused_ideal_layer",
-            metadata=gather_metadata("fused_ideal_layer", rows, n_s, f, h),
-        )(nbr, wts, x_rows, w, b)
+            metadata=gather_metadata("fused_ideal_layer", padded, n_s, f, h,
+                                     block_rows=block),
+        )(nbr, x_rows, wts.reshape(padded, n_s), w, b)
+        # Without the barrier XLA fuses the chunk's write into the stacked
+        # output with the launch, and a profile then shows a fusion that
+        # carries neither the custom call nor its kernel_metadata.
+        return jax.lax.optimization_barrier(out)[:rows]
 
-    return map_row_chunks(call, neighbors, weights).reshape(nd, h)
+    return map_row_chunks(call, neighbors, weights)
 
 
 @functools.partial(jax.jit, static_argnames="interpret")

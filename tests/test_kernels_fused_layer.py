@@ -14,10 +14,12 @@ import jax.numpy as jnp
 
 from _hyp import given, settings, st
 from repro.core import gnn, random_graph
+from repro.kernels._gather import block_rows
 from repro.kernels.crossbar_mvm import CrossbarNumerics
 from repro.kernels.fused_layer import (fused_gnn_forward,
                                        fused_gnn_forward_batched,
-                                       fused_gnn_layer, fused_layer_ref)
+                                       fused_gnn_layer, fused_ideal_layer,
+                                       fused_layer_ref)
 
 QUANT = CrossbarNumerics(in_bits=8, w_bits=8, adc_bits=12, rows_per_xbar=64)
 
@@ -48,6 +50,7 @@ def _check(x, nbr, wts, w, b, cfg, relu):
     (23, 50, 17, 11, 5),       # odd shapes, Nd != N
     (7, 300, 33, 7, 1),        # F > rows_per_xbar (multi K-tile), S = 1
     (40, 16, 128, 40, 9),      # H > F
+    (40, 496, 64, 100, 8),     # collab's F = 496, padded to 512 lanes
 ])
 def test_matches_composed_ideal(n, f, h, nd, s, relu):
     x, nbr, wts, w, b = _case(n, f, h, nd, s, seed=n + f)
@@ -63,6 +66,71 @@ def test_matches_composed_ideal(n, f, h, nd, s, relu):
 def test_matches_composed_quantized(n, f, h, nd, s, relu):
     x, nbr, wts, w, b = _case(n, f, h, nd, s, seed=n + f)
     _check(x, nbr, wts, w, b, QUANT, relu)
+
+
+@pytest.mark.parametrize("rows,slots,width,block", [
+    (4096, 8, 512, 256),       # collab layer 1: two 4 MiB buffers
+    (4096, 8, 128, 1024),      # collab layer 2
+    (4096, 8, 496, 256),       # lanes padded to 512
+    (20, 8, 128, 24),          # one block: the rows rounded up to 8
+    (6553, 5, 128, 1312),      # a divisor of 6,560 under the 1,632 cap
+    (300, 8, 1024, 16),        # wide rows: small divisors only
+])
+def test_block_rows(rows, slots, width, block):
+    assert block_rows(rows, slots, width) == block
+
+
+def _block_case(n, f, h, nd, s, seed, tables="random"):
+    x, nbr, wts, w, b = _case(n, f, h, nd, s, seed=seed)
+    if tables == "padded":     # weight-0 padding slots naming row 0
+        pad = np.random.default_rng(seed).random((nd, s)) < 0.3
+        nbr = jnp.where(pad, 0, nbr)
+        wts = jnp.where(pad, 0.0, wts)
+    if tables == "repeated":   # one source row named by half the slots
+        nbr = nbr.at[:, : (s + 1) // 2].set(3 % n)
+    return x, nbr, wts, w, b
+
+
+@pytest.mark.parametrize("n,f,h,nd,s,tables", [
+    (50, 128, 128, 203, 8, "random"),    # nd not a multiple of the block
+    (300, 1024, 16, 300, 8, "random"),   # several blocks in one launch
+    (30, 128, 64, 5, 8, "random"),       # nd smaller than one block
+    (300, 128, 128, 4500, 8, "random"),  # more than one TABLE_ENTRIES chunk
+    (40, 256, 32, 64, 8, "repeated"),    # one row named by several slots
+    (4, 128, 16, 24, 6, "random"),       # four rows for all slots of a block
+    (60, 128, 32, 70, 8, "padded"),      # weight-0 padding slots
+    (30, 128, 128, 17, 1, "random"),     # S = 1
+])
+def test_block_gather_matches_ref(n, f, h, nd, s, tables):
+    x, nbr, wts, w, b = _block_case(n, f, h, nd, s, n + nd, tables)
+    for relu in (False, True):
+        ref = fused_layer_ref(x, nbr, wts, w, b, relu=relu)
+        out = fused_ideal_layer(x, nbr, wts, w, b, relu=relu)
+        scale = float(jnp.abs(ref).max()) or 1.0
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-4 * scale)
+
+
+@jax.jit
+def _slot_order_layer(x, nbr, wts, w):
+    z = jnp.zeros((nbr.shape[0], x.shape[1]), jnp.float32)
+    for k in range(nbr.shape[1]):
+        z = z + wts[:, k:k + 1] * x[nbr[:, k]]
+    return jnp.dot(z, w, preferred_element_type=jnp.float32)
+
+
+@pytest.mark.parametrize("nd,s,tables", [
+    (203, 8, "random"), (4500, 8, "padded"), (9, 3, "repeated")])
+def test_block_gather_z_bit_exact(nd, s, tables):
+    """Z is each row's float32 sum over its slots in slot order, bit for
+    bit: with W = I and no bias the layer returns Z itself. The reference
+    is jitted, as the kernel is, so both sums compile alike."""
+    f = 128
+    x, nbr, wts, _, _ = _block_case(64, f, f, nd, s, nd, tables)
+    eye = jnp.eye(f, dtype=jnp.float32)
+    want = _slot_order_layer(x, nbr, wts, eye)
+    got = fused_ideal_layer(x, nbr, wts, eye, jnp.zeros((f,)))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_signed_activations_quantized():

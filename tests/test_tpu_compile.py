@@ -107,10 +107,11 @@ def _launch(kernel, **grid):
 
 # (kernel, the metadata its launch states) at small shapes: the fused and
 # csr kernels gather chunks of 4,096 rows x 8 slots, F=216 padded to 128
-# lanes (to a whole crossbar, 512 rows, on the bit-accurate path)
+# lanes (to a whole crossbar, 512 rows, on the bit-accurate path); the
+# ideal kernel takes them 512 rows a grid step (two 4 MiB row buffers)
 LAUNCHES = {
     "fused_ideal_layer": [_launch("fused_ideal_layer", rows=4096, slots=S,
-                                  f_in=256, f_out=128)],
+                                  f_in=256, f_out=128, block_rows=512)],
     "fused_quant_layer": [_launch("fused_zmax", rows=4096, slots=S,
                                   f_in=512, f_out=2),
                           _launch("fused_quant_layer", rows=4096, slots=S,
@@ -152,8 +153,11 @@ def _compile_small(name, sharding):
 def test_launches_carry_their_name_and_metadata(one_chip, name):
     """Every launch is an instruction named after its kernel, and its
     ``kernel_metadata`` states the kernel and its grid: the trace event of
-    a launch is that instruction's text."""
+    a launch is that instruction's text. No fusion takes a launch in (a
+    profile would name the fusion, and its event would carry neither)."""
     text = _compile_small(name, one_chip)
+    assert not re.search(r"%[\w.]+ = [^\n]* fusion\([^\n]*kind=kCustom",
+                         text)
     found = {}
     for m in re.finditer(r'%([\w.]+) = [^\n]*custom_call_target='
                          r'"tpu_custom_call"[^\n]*kernel_metadata=(\{.*?\})',
