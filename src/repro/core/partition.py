@@ -21,6 +21,7 @@ executable (DESIGN.md §7).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 
@@ -603,14 +604,18 @@ class ExecutionPlan:
         fn, args = self._forward_parts(cfg, mesh=mesh, mode=mode,
                                        overlap=overlap)
         return instrument_forward(self, self.gnn_config(cfg), mode,
-                                  lambda params: fn(params, *args))
+                                  lambda params: fn(params, *args),
+                                  spmd=self._spmd(mesh))
 
     def lower_forward(self, params, cfg, mesh=None, mode: str = "alltoall"):
-        """``jax.stages.Lowered`` of the program ``make_forward`` runs, with
-        the plan's tables as arguments rather than embedded constants —
+        """``jax.stages.Lowered`` of the program ``make_forward`` runs —
         what a check that the kernels were compiled, not interpreted, reads
-        (``tpu_custom_call`` in ``as_text()``). Dense layouts only: the
-        bucketed forward is a host loop over buckets, not one program."""
+        (``tpu_custom_call`` in ``as_text()``). The features and the
+        sample are arguments of its ``main``; on the SPMD runtime so are
+        the exchange tables, each split over the mesh like the features,
+        while the emulated runtime embeds its tables as constants. Dense
+        layouts only: the bucketed forward is a host loop over buckets,
+        not one program."""
         if self.bucketed is not None:
             raise ValueError("a bucketed plan's forward dispatches per "
                              "bucket from the host; there is no single "
@@ -640,8 +645,7 @@ class ExecutionPlan:
                                                 overlap=overlap)
             feats = tuple(jnp.asarray(f) for f in self.feats)
             return fn, (feats, nbrs, wtss)
-        spmd = (mesh is not None and mesh.size == self.n_clusters
-                and self.setting != "centralized")
+        spmd = self._spmd(mesh)
         args = self.device_inputs(mesh if spmd else None)
         if self.setting == "centralized":
             from repro.core import gnn
@@ -651,19 +655,26 @@ class ExecutionPlan:
                                                 make_emulated_semi_forward,
                                                 make_semi_forward)
             plan = build_two_tier_plan(self.hier)
-            fn = (make_semi_forward(mesh, cfg, plan, mode=mode) if spmd
-                  else make_emulated_semi_forward(cfg, plan, mode=mode))
-            return fn, args
-        from repro.distributed.halo import (build_halo_plan,
-                                            make_decentralized_forward,
-                                            make_emulated_forward)
-        plan = build_halo_plan(self.part)
-        if spmd:
-            fn = make_decentralized_forward(mesh, cfg, plan, self.part.n_max,
-                                            mode=mode)
+            if not spmd:
+                return make_emulated_semi_forward(cfg, plan, mode=mode), args
+            fwd = make_semi_forward(mesh, cfg, plan, mode=mode)
         else:
-            fn = make_emulated_forward(cfg, plan, mode=mode)
-        return fn, args
+            from repro.distributed.halo import (build_halo_plan,
+                                                make_decentralized_forward,
+                                                make_emulated_forward)
+            plan = build_halo_plan(self.part)
+            if not spmd:
+                return make_emulated_forward(cfg, plan, mode=mode), args
+            fwd = make_decentralized_forward(mesh, cfg, plan,
+                                             self.part.n_max, mode=mode)
+        # the exchange tables are placed once, split over the mesh as
+        # device_inputs splits the features: each device its own slice
+        return fwd.program, (*args, fwd.placed())
+
+    def _spmd(self, mesh) -> bool:
+        """Whether ``mesh`` selects the SPMD runtime: one device a cluster."""
+        return (mesh is not None and mesh.size == self.n_clusters
+                and self.setting != "centralized")
 
     def device_inputs(self, mesh=None):
         """The dense plan's (feats, neighbors, weights) as device arrays.
@@ -706,11 +717,20 @@ class ExecutionPlan:
                 return full
             if self.setting == "centralized":
                 return out[0]
-            full = np.zeros((self.graph.n_nodes, out.shape[-1]), out.dtype)
-            for c in range(self.n_clusters):
-                m = self.part.local_mask[c]
-                full[self.part.local_nodes[c][m]] = out[c][m]
-            return full
+            return np.take(out.reshape(-1, out.shape[-1]), self._owner_rows,
+                           axis=0)
+
+    @functools.cached_property
+    def _owner_rows(self) -> np.ndarray:
+        """[N] row of each node in the dense output flattened to
+        ``[K * n_max, D]``: ``scatter`` is then one gather, where a masked
+        scatter per cluster took several times as long on the host."""
+        k, n_max = self.part.local_nodes.shape
+        rows = np.zeros(self.graph.n_nodes, np.int64)
+        for c in range(k):
+            m = self.part.local_mask[c]
+            rows[self.part.local_nodes[c][m]] = c * n_max + np.nonzero(m)[0]
+        return rows
 
     def layout_stats(self, cfg=None) -> dict:
         """Deterministic padded-layout accounting for this plan.

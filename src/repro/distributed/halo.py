@@ -33,10 +33,18 @@ in one ``fused_gnn_layer`` kernel launch with Z resident in VMEM (so the
 decentralized and semi-decentralized settings get the same HBM-traffic win
 as the centralized path — DESIGN.md §5).
 
+Where the exchange tables live: the SPMD forwards (``SpmdForward``) take
+them as arguments of the jitted program, each split over the mesh's
+cluster axis, so every device holds only its own ``[h_max]`` / ``[K,
+s_max]`` slices and the compiled module holds no copy of any of them. The
+emulated forwards close over them as constants on their one device.
+
 The bucketed host loops open ``halo.gather`` and ``halo.mvm`` spans per
 layer and bucket (``halo.tier0_gather`` for the semi tier 0). They time
 dispatch and never wait for the device; inside a JAX profile they land on
-the host timeline beside the device trace (DESIGN.md §14).
+the host timeline beside the device trace (DESIGN.md §14). The SPMD
+forward is one jitted call and opens no span inside; its collectives
+appear in a profile's device trace under their HLO opcodes.
 """
 from __future__ import annotations
 
@@ -46,6 +54,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import telemetry as tel
@@ -141,15 +150,72 @@ def _layer_step(table, nbr, wts, layer, cfg, act: bool):
     return jax.nn.relu(x) if act else x
 
 
-def _plan_consts(plan: HaloPlan) -> dict:
-    return jax.tree.map(
-        jnp.asarray,
-        dict(src_c=plan.src_cluster, src_s=plan.src_slot,
-             hmask=plan.halo_mask.astype(np.float32),
-             send_slot=plan.send_slot,
-             send_mask=plan.send_mask.astype(np.float32),
-             recv_to_halo=plan.recv_to_halo,
-             recv_mask=plan.recv_mask.astype(np.float32)))
+# the tables each exchange mode reads
+_MODE_TABLES = {"allgather": ("src_c", "src_s", "hmask"),
+                "alltoall": ("send_slot", "send_mask", "recv_to_halo",
+                             "recv_mask")}
+
+
+def exchange_tables(plan: HaloPlan, mode: str) -> dict:
+    """The tables the ``mode`` exchange reads, as numpy arrays with the
+    cluster axis leading (masks as float32 multipliers)."""
+    every = dict(src_c=plan.src_cluster, src_s=plan.src_slot,
+                 hmask=plan.halo_mask.astype(np.float32),
+                 send_slot=plan.send_slot,
+                 send_mask=plan.send_mask.astype(np.float32),
+                 recv_to_halo=plan.recv_to_halo,
+                 recv_mask=plan.recv_mask.astype(np.float32))
+    return {n: every[n] for n in _MODE_TABLES[mode]}
+
+
+def _plan_consts(plan: HaloPlan, mode: str) -> dict:
+    return jax.tree.map(jnp.asarray, exchange_tables(plan, mode))
+
+
+@dataclasses.dataclass
+class SpmdForward:
+    """An SPMD forward whose exchange tables are arguments of its program.
+
+    ``program(params, feats, nbr, wts, tables)`` is the jitted
+    ``shard_map``; ``tables`` maps names to ``[K, ...]`` arrays that
+    ``sharding`` splits over the mesh's cluster axis, so each device holds
+    only its own slice and the compiled module embeds none of them.
+    Called as ``fwd(params, feats, nbr, wts)`` it places the tables on
+    first use and passes them on every call; ``lower`` needs only their
+    shapes, so it also lowers for devices that are described, not
+    attached."""
+    program: object
+    tables: dict
+    sharding: NamedSharding
+    _placed: bool = dataclasses.field(default=False, init=False, repr=False)
+
+    def placed(self) -> dict:
+        """The tables on the mesh (placed once)."""
+        if not self._placed:
+            self.tables = jax.device_put(self.tables, self.sharding)
+            self._placed = True
+        return self.tables
+
+    def __call__(self, params, feats, nbr, wts):
+        return self.program(params, feats, nbr, wts, self.placed())
+
+    def lower(self, params, feats, nbr, wts):
+        specs = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=self.sharding),
+            self.tables)
+        return self.program.lower(params, feats, nbr, wts, specs)
+
+
+def _spmd_forward(mesh, device_fn, tables: dict, axis: str) -> SpmdForward:
+    """Jit ``device_fn(params, feats, nbr, wts, tables)`` as a
+    ``shard_map`` over ``axis``: params replicated, everything else split
+    on its leading cluster axis."""
+    shard = P(axis)
+    fn = jax.shard_map(device_fn, mesh=mesh,
+                       in_specs=(P(), shard, shard, shard, shard),
+                       out_specs=shard, check_vma=False)
+    return SpmdForward(jax.jit(fn), tables, NamedSharding(mesh, shard))
 
 
 def _spmd_layers(params, x, nbr, wts, cfg, t, mode, h_max, axis):
@@ -177,31 +243,20 @@ def make_decentralized_forward(mesh, cfg, plan: HaloPlan, n_max: int,
     Inputs (sharded on the leading cluster axis over ``axis``):
       feats   [K, n_max, F_in]   owned node features
       nbr/wts [K, n_max, S]      device-local padded subgraph
-    Returns [K, n_max, out_dim] embeddings for owned nodes.
+    Returns an ``SpmdForward``: ``fwd(params, feats, nbr, wts)`` gives the
+    [K, n_max, out_dim] embeddings of owned nodes, and ``fwd.program``
+    takes the ``mode``'s exchange tables as a fifth argument.
     """
     assert mode in EXCHANGE_MODES, mode
     h_max = plan.src_cluster.shape[1]
-    consts = _plan_consts(plan)
-    names = tuple(consts)
 
-    def device_fn(params, feats, nbr, wts, *tables):
-        t = {n: v[0] for n, v in zip(names, tables)}
+    def device_fn(params, feats, nbr, wts, tables):
+        t = {n: v[0] for n, v in tables.items()}
         x = _spmd_layers(params, feats[0], nbr[0], wts[0], cfg, t, mode,
                          h_max, axis)
         return x[None]
 
-    shard = P(axis)
-    fn = jax.shard_map(
-        device_fn, mesh=mesh,
-        in_specs=(P(),) + (shard,) * (3 + len(names)),
-        out_specs=shard,
-        check_vma=False)
-
-    @jax.jit
-    def forward(params, feats, nbr, wts):
-        return fn(params, feats, nbr, wts, *(consts[n] for n in names))
-
-    return forward
+    return _spmd_forward(mesh, device_fn, exchange_tables(plan, mode), axis)
 
 
 def _emulated_exchange(x, t, mode, h_max):
@@ -251,7 +306,7 @@ def make_emulated_forward(cfg, plan: HaloPlan, mode: str = "allgather"):
     """
     assert mode in EXCHANGE_MODES, mode
     h_max = plan.src_cluster.shape[1]
-    consts = _plan_consts(plan)
+    consts = _plan_consts(plan, mode)
 
     @jax.jit
     def forward(params, feats, nbr, wts):
@@ -285,10 +340,9 @@ def build_two_tier_plan(hier: HierPartition) -> TwoTierPlan:
                        hier.region.n_max)
 
 
-def _tier0_consts(plan: TwoTierPlan) -> dict:
-    return dict(gspoke=jnp.asarray(plan.gather_spoke),
-                gslot=jnp.asarray(plan.gather_slot),
-                gmask=jnp.asarray(plan.gather_mask.astype(np.float32)))
+def _tier0_tables(plan: TwoTierPlan) -> dict:
+    return dict(gspoke=plan.gather_spoke, gslot=plan.gather_slot,
+                gmask=plan.gather_mask.astype(np.float32))
 
 
 def make_semi_forward(mesh, cfg, plan: TwoTierPlan,
@@ -301,34 +355,22 @@ def make_semi_forward(mesh, cfg, plan: TwoTierPlan,
     Tier 0 assembles the head's region table from its co-located spokes
     (device-local gather — the access-link upload is billed by the traffic
     accountant, not moved over the mesh); tier 1 runs the per-layer
-    head<->head halo exchange collective. Returns [R, n_max, out_dim].
+    head<->head halo exchange collective. Returns an ``SpmdForward``
+    (``make_decentralized_forward``) giving [R, n_max, out_dim].
     """
     assert mode in EXCHANGE_MODES, mode
     h_max = plan.h_max
-    consts = dict(_tier0_consts(plan), **_plan_consts(plan.region))
-    names = tuple(consts)
 
-    def device_fn(params, spoke_feats, nbr, wts, *tables):
-        t = {n: v[0] for n, v in zip(names, tables)}
+    def device_fn(params, spoke_feats, nbr, wts, tables):
+        t = {n: v[0] for n, v in tables.items()}
         x = (spoke_feats[0][t["gspoke"], t["gslot"]]
              * t["gmask"][:, None])                     # tier 0: [n_max, F]
         x = _spmd_layers(params, x, nbr[0], wts[0], cfg, t, mode, h_max,
                          axis)
         return x[None]
 
-    shard = P(axis)
-    fn = jax.shard_map(
-        device_fn, mesh=mesh,
-        in_specs=(P(),) + (shard,) * (3 + len(names)),
-        out_specs=shard,
-        check_vma=False)
-
-    @jax.jit
-    def forward(params, spoke_feats, nbr, wts):
-        return fn(params, spoke_feats, nbr, wts,
-                  *(consts[n] for n in names))
-
-    return forward
+    tables = dict(_tier0_tables(plan), **exchange_tables(plan.region, mode))
+    return _spmd_forward(mesh, device_fn, tables, axis)
 
 
 @dataclasses.dataclass
@@ -526,8 +568,8 @@ def make_emulated_semi_forward(cfg, plan: TwoTierPlan,
     """
     assert mode in EXCHANGE_MODES, mode
     h_max = plan.h_max
-    t0 = _tier0_consts(plan)
-    consts = _plan_consts(plan.region)
+    t0 = jax.tree.map(jnp.asarray, _tier0_tables(plan))
+    consts = _plan_consts(plan.region, mode)
 
     @jax.jit
     def forward(params, spoke_feats, nbr, wts):

@@ -38,6 +38,11 @@ class TrafficReport:
     to its head (semi only — empty [0, 0] otherwise). Bytes follow from the
     per-layer feature dims: tier 0 moves input features once, tier 1 moves
     the layer's input dim every layer.
+
+    ``halo_rows`` and ``send_rows`` are the exchange as launched: the halo
+    rows each device's table holds (``h_max``), and the rows each device's
+    collective sends each peer, itself included (``s_max`` for
+    ``alltoall``, the whole padded table ``n_max`` for ``allgather``).
     """
     setting: str
     mode: str
@@ -45,10 +50,20 @@ class TrafficReport:
     tier0_rows: np.ndarray     # [R, P] int64
     tier1_rows: np.ndarray     # [K, K] int64
     itemsize: int = ITEMSIZE
+    halo_rows: int = 0
+    send_rows: int = 0
 
     @property
     def n_devices(self) -> int:
         return self.tier1_rows.shape[0]
+
+    def launched_bytes(self) -> int:
+        """Bytes each device's exchange collective sends in one forward,
+        summed over layers, as launched: ``K x send_rows`` rows a layer at
+        the layer's input width, padding and the self block included. Over
+        the devices, less ``tier1_bytes().sum()``, it is the padding."""
+        return (self.n_devices * self.send_rows * sum(self.layer_dims)
+                * self.itemsize)
 
     @property
     def n_layers(self) -> int:
@@ -239,4 +254,6 @@ def measure_execution(plan, cfg=None, mode: str = "alltoall") -> TrafficReport:
     tier1 = exchange_rows(halo_plan, mode, plan.part.n_max)
     tier0 = (plan.hier.spoke_mask.sum(axis=2).astype(np.int64)
              if plan.setting == "semi" else no_spokes)
-    return TrafficReport(plan.setting, mode, dims, tier0, tier1)
+    send_rows = halo_plan.s_max if mode == "alltoall" else plan.part.n_max
+    return TrafficReport(plan.setting, mode, dims, tier0, tier1,
+                         halo_rows=plan.part.h_max, send_rows=send_rows)

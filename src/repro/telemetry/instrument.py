@@ -15,7 +15,13 @@ boundary instead:
      byte totals therefore equal ``TrafficReport.total_bytes()`` exactly,
      by construction (the obs_overhead gate asserts this per setting);
   3. increments the ``halo.shipped_bytes`` counter so byte totals survive
-     span-ring eviction.
+     span-ring eviction;
+  4. on the SPMD runtime, states on ``plan.forward`` what its exchange
+     collective moves as launched: ``chips``, the per-chip ``halo_rows``
+     and ``send_rows`` (rows to each peer, padded), and ``launched_bytes``
+     (what each chip's collective sends in one forward, padding and the
+     self block included). ``halo.exchange`` keeps the true bytes, summed
+     over the chips; ``chips x launched_bytes`` less them is the padding.
 
 The traffic report is computed lazily on the first *traced* call and
 cached — with telemetry disabled the wrapper is a flag check plus the
@@ -31,8 +37,10 @@ from . import get_registry, get_tracer
 __all__ = ["instrument_forward", "record_commit", "record_streaming_traffic"]
 
 
-def instrument_forward(plan, cfg, mode: str, fwd: Callable) -> Callable:
-    """Wrap a plan forward with span + exact bytes accounting."""
+def instrument_forward(plan, cfg, mode: str, fwd: Callable,
+                       spmd: bool = False) -> Callable:
+    """Wrap a plan forward with span + exact bytes accounting; ``spmd``
+    says the forward runs the exchange as a collective over a mesh."""
     state: Dict[str, Any] = {}
 
     def run(params):
@@ -44,10 +52,16 @@ def instrument_forward(plan, cfg, mode: str, fwd: Callable) -> Callable:
             rep = plan.measured_traffic(cfg, mode=mode)
             tier0 = int(rep.tier0_bytes().sum())
             per_layer = [int(b) for b in rep.tier1_bytes().sum(axis=1)]
-            billing = state["billing"] = (tier0, per_layer, tier0 + sum(per_layer))
-        tier0, per_layer, total = billing
+            launched = (dict(chips=rep.n_devices, halo_rows=rep.halo_rows,
+                             send_rows=rep.send_rows,
+                             launched_bytes=rep.launched_bytes())
+                        if spmd else {})
+            billing = state["billing"] = (tier0, per_layer,
+                                          tier0 + sum(per_layer), launched)
+        tier0, per_layer, total, launched = billing
         with tracer.span("plan.forward", setting=plan.setting,
-                         backend=plan.backend, clusters=plan.n_clusters):
+                         backend=plan.backend, clusters=plan.n_clusters,
+                         **launched):
             if tier0:
                 with tracer.span("halo.tier0_upload") as s0:
                     s0.add_bytes(tier0)

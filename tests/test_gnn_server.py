@@ -119,3 +119,20 @@ def test_device_inputs_split_clusters_over_mesh():
         assert arr.sharding.spec == PartitionSpec("data")
         assert [s.device for s in arr.addressable_shards] == \
             list(mesh.devices.flat)
+
+
+def test_scatter_puts_each_cluster_row_at_its_node():
+    """``scatter`` of a dense per-cluster output is one gather; it places
+    exactly what the masked per-cluster loop places, bit for bit."""
+    rng = np.random.default_rng(0)
+    for setting in ("decentralized", "semi"):
+        srv, _, g = _server(setting=setting, n_clusters=3)
+        part = srv.plan.part
+        out = rng.normal(size=(*part.local_nodes.shape, 5)).astype(
+            np.float32)
+        want = np.full((g.n_nodes, 5), np.nan, np.float32)
+        for c in range(part.n_clusters):
+            m = part.local_mask[c]
+            want[part.local_nodes[c][m]] = out[c][m]
+        assert not np.isnan(want).any(), "every node is some cluster's row"
+        np.testing.assert_array_equal(srv.plan.scatter(out), want)
