@@ -826,6 +826,33 @@ class ExecutionPlan:
                                  calibration=calibration)
         return self.mapping.mapping_report()
 
+    def gather_dmas(self, cfg) -> dict:
+        """The DMAs of one forward's ``fused_ideal_layer`` launches, summed
+        over layers and clusters: ``{"row_dmas", "block_dmas"}``, counted
+        on this plan's samples as each launch counts them
+        (``fused_layer.ideal_layer_dmas``). Empty where the forward makes
+        no such launch: another backend, or bit-accurate numerics."""
+        cfg = self.gnn_config(cfg)
+        if cfg.backend != "fused" or not cfg.numerics.ideal:
+            return {}
+        from repro.kernels.fused_layer import ideal_layer_dmas
+        if self.bucketed is not None:      # a bucket's table: owned + halo
+            b = self.bucketed
+            launches = [(nbr, n + h) for nbrs, n, h in
+                        zip(self.neighbors, b.n_caps, b.h_caps)
+                        for nbr in nbrs]
+        else:
+            n = (self.graph.n_nodes if self.setting == "centralized"
+                 else self.part.n_max + self.part.h_max)
+            launches = [(nbr, n) for nbr in self.neighbors]
+        dims = (cfg.in_dim, *cfg.hidden_dims, cfg.out_dim)
+        rows = blocks = 0
+        for f_in, f_out in zip(dims[:-1], dims[1:]):
+            for nbr, n in launches:
+                r, b = ideal_layer_dmas(nbr, n, f_in, f_out, tuned=cfg.tuned)
+                rows, blocks = rows + r, blocks + b
+        return {"row_dmas": rows, "block_dmas": blocks}
+
     def measured_traffic(self, cfg=None, mode: str = "alltoall"):
         """Measured wire traffic of this plan's exchanges — the runtime
         counterpart of ``predicted_metrics`` (bytes per device per layer,

@@ -8,7 +8,11 @@ table, in one of two ways (DESIGN.md §5):
     BlockSpec pipeline fetches at step ``(i, s)``.
   * A block of rows per grid step (``fused_ideal_layer``): the table stays
     in HBM and the kernel copies each row by hand, ``block_rows`` rows and
-    all their slots a step (``block_rows`` says how many).
+    all their slots a step (``block_rows`` says how many). Where one slot
+    names consecutive table rows over the whole block, as a self loop does
+    when the destination rows are the table's own, ``block_runs`` finds
+    the run and the kernel moves it with one DMA of ``block_rows`` rows
+    instead of one a row; ``gather_dmas`` counts what a launch issues.
 
 Two limits of the TPU compiler shape both:
 
@@ -19,9 +23,10 @@ Two limits of the TPU compiler shape both:
     ``(1, F)`` then equal the array's.
   * SMEM. Scalar-prefetched operands sit whole in SMEM (1 MiB on v5e), and
     a 2-D ``[Nd, S]`` table is lane-padded to 128 there. The tables go in
-    flat, and ``map_row_chunks`` cuts the destination rows into chunks of at
-    most ``TABLE_ENTRIES`` entries per table, one kernel call per chunk, so
-    the SMEM a call needs is bounded at any node count.
+    flat, and ``chunk_tables`` cuts the destination rows into chunks of at
+    most ``TABLE_ENTRIES`` entries per table, one kernel call per chunk
+    (``map_row_chunks``), so the SMEM a call needs is bounded at any node
+    count.
 
 Each destination row is computed by the same ops in the same order in
 every chunk and every block, so neither changes a single output bit.
@@ -34,6 +39,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 TABLE_ENTRIES = 1 << 15     # per scalar-prefetched table: 128 KiB of 32-bit
@@ -76,6 +82,72 @@ def gather_metadata(kernel: str, rows: int, slots: int, f_in: int,
     return {k: str(v) for k, v in meta.items()}
 
 
+def chunk_rows(nd: int, slots: int) -> int:
+    """Destination rows of one kernel call: all ``nd`` where their tables
+    fit ``TABLE_ENTRIES``, else as many as do."""
+    return min(nd, max(TABLE_ENTRIES // slots, 1))
+
+
+def chunk_tables(table, rows: int, padded: int):
+    """``[Nd, S] -> [C, padded, S]``: the table cut into chunks of ``rows``
+    destination rows, each chunk zero padded to ``padded`` rows, the short
+    last one too. Works on numpy and jnp tables alike."""
+    xp = np if isinstance(table, np.ndarray) else jnp
+    nd, s = table.shape
+    c = -(-nd // rows)
+    t = xp.pad(table, ((0, c * rows - nd), (0, 0))).reshape(c, rows, s)
+    return xp.pad(t, ((0, 0), (0, padded - rows), (0, 0)))
+
+
+def map_chunks(call, *tables):
+    """``call`` over the leading chunk axis of ``tables``, stacked: one
+    traced call for one chunk, else a ``lax.map``."""
+    if tables[0].shape[0] == 1:
+        return call(*(t[0] for t in tables))[None]
+    return jax.lax.map(lambda t: call(*t), tables)
+
+
+def block_runs(nbr, block: int, n_rows: int):
+    """Per block of ``block`` destination rows, the slot whose indices over
+    the block are an ascending run, and the run's first table row.
+
+    ``nbr`` is ``[..., M, S]`` with ``M`` a multiple of ``block``, numpy or
+    jnp. Slot ``s`` of block ``j`` is a run where ``nbr[jR + r, s] ==
+    nbr[jR, s] + r`` for every ``r < R`` and the run lies inside the
+    ``n_rows``-row table; its ``R`` rows are then one contiguous slice.
+    Returns ``[..., M // block, 2]`` int32: ``(slot, first row)`` of the
+    block's first run slot, or ``(-1, 0)`` where it has none. Padding
+    rows name row 0, so a block of two rows or more that holds one is no
+    run."""
+    xp = np if isinstance(nbr, np.ndarray) else jnp
+    *lead, m, s = nbr.shape
+    t = nbr.reshape(*lead, m // block, block, s)
+    first = t[..., 0, :]                                    # [..., J, S]
+    step = xp.arange(block, dtype=t.dtype)[:, None]
+    run = (xp.all(t == first[..., None, :] + step, axis=-2)
+           & (first >= 0) & (first <= n_rows - block))
+    lane = xp.arange(s, dtype=xp.int32)
+    slot = xp.min(xp.where(run, lane, s), axis=-1)
+    start = xp.sum(xp.where(lane == slot[..., None], first, 0), axis=-1)
+    return xp.stack([xp.where(slot < s, slot, -1), start],
+                    axis=-1).astype(xp.int32)
+
+
+def gather_dmas(neighbors: np.ndarray, n_rows: int, width: int) -> tuple:
+    """``(row DMAs, block DMAs)`` that one ``fused_ideal_layer`` launch
+    issues over the ``[Nd, S]`` sample of an ``n_rows`` x ``width`` table:
+    the same chunks, blocks and runs the launch computes, padding rows
+    included. A run slot takes one block DMA in place of ``block_rows``
+    row DMAs."""
+    nd, s = neighbors.shape
+    rows = chunk_rows(nd, s)
+    block = block_rows(rows, s, width)
+    padded = -(-rows // block) * block
+    nbr = chunk_tables(np.asarray(neighbors), rows, padded)
+    runs = int((block_runs(nbr, block, n_rows)[..., 0] >= 0).sum())
+    return nbr.size - runs * block, runs
+
+
 def map_row_chunks(call, neighbors: jax.Array, weights: jax.Array):
     """Run ``call(nbr_flat, wts_flat) -> [rows, ...]`` over chunks of the
     destination rows and return the stacked ``[Nd, ...]`` result.
@@ -85,13 +157,8 @@ def map_row_chunks(call, neighbors: jax.Array, weights: jax.Array):
     chunk is padded with weight-0 rows whose outputs are dropped.
     """
     nd, s = neighbors.shape
-    weights = weights.astype(jnp.float32)
-    rows = max(TABLE_ENTRIES // s, 1)
-    if nd <= rows:
-        return call(neighbors.reshape(-1), weights.reshape(-1))
-    n_chunks = -(-nd // rows)
-    pad = ((0, n_chunks * rows - nd), (0, 0))
-    nbr = jnp.pad(neighbors, pad).reshape(n_chunks, rows * s)
-    wts = jnp.pad(weights, pad).reshape(n_chunks, rows * s)
-    out = jax.lax.map(lambda t: call(*t), (nbr, wts))
-    return out.reshape(n_chunks * rows, *out.shape[2:])[:nd]
+    rows = chunk_rows(nd, s)
+    nbr, wts = (chunk_tables(t, rows, rows).reshape(-1, rows * s)
+                for t in (neighbors, weights.astype(jnp.float32)))
+    out = map_chunks(call, nbr, wts)
+    return out.reshape(-1, *out.shape[2:])[:nd]

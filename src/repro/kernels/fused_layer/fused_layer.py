@@ -23,6 +23,15 @@ stays in HBM; the rows of block ``j + 1`` are copied by hand, one DMA per
                 z[R, F]  = sum_s w[:, s] * buf[j % 2, s]   (slot order, f32)
                 out[R, H] = act(z @ W + b)                 (MXU, Z in VMEM)
 
+A slot whose rows over block ``j`` are consecutive table rows, ``nbr[jR +
+r, s] == nbr[jR, s] + r`` (a self loop where the destination rows lead the
+table), is a run: one DMA of ``[R, 1, F]`` moves it, and only the other
+slots take row DMAs. The launch finds the runs once (``_gather.block_runs``,
+the first run slot of each block) and prefetches them beside ``nbr``; the
+kernel picks one of ``S + 1`` static issue loops per block, so the loop
+over rows tests nothing per DMA. The bytes land where the row DMAs would
+put them, so the one wait per buffer and every output bit are unchanged.
+
 The bit-accurate path's two kernels gather one row-slot per grid step:
 
   grid (node i, sample s):
@@ -57,40 +66,58 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._gather import (block_rows, gather_metadata,
+from repro.kernels._gather import (block_rows, block_runs, chunk_rows,
+                                  chunk_tables, gather_metadata, map_chunks,
                                   map_row_chunks, row_block, rows_view)
 from repro.kernels._interpret import resolve_interpret
 from repro.kernels.crossbar_mvm.ref import CrossbarNumerics
 
 
-def _fused_ideal_kernel(nbr_ref, x_hbm, wts_ref, w_ref, b_ref, out_ref,
-                        buf, sem, *, relu: bool):
+def _fused_ideal_kernel(nbr_ref, runs_ref, x_hbm, wts_ref, w_ref, b_ref,
+                        out_ref, buf, sem, *, relu: bool):
     j = pl.program_id(0)
     n_s, block, _, f = buf.shape[1:]
 
     def fetch(blk, slot):
-        # one DMA per (row, slot) of block ``blk``, all on ``sem[slot]``
+        # block ``blk`` into ``buf[slot]``, every DMA on ``sem[slot]``: its
+        # run slot, if any, in one DMA, the other slots one DMA per row
         base = blk * block * n_s
+        run, start = runs_ref[2 * blk], runs_ref[2 * blk + 1]
 
-        def row(r, carry):
-            for s in range(n_s):
-                pltpu.make_async_copy(x_hbm.at[nbr_ref[base + r * n_s + s]],
-                                      buf.at[slot, s, r],
-                                      sem.at[slot]).start()
-            return carry
+        def rows(skip):
+            def row(r, carry):
+                for s in range(n_s):
+                    if s != skip:
+                        pltpu.make_async_copy(
+                            x_hbm.at[nbr_ref[base + r * n_s + s]],
+                            buf.at[slot, s, r], sem.at[slot]).start()
+                return carry
 
-        jax.lax.fori_loop(0, block, row, 0)
+            jax.lax.fori_loop(0, block, row, 0)
 
-    @pl.when(j == 0)
-    def _first():
-        fetch(0, 0)
+        @pl.when(run < 0)
+        def _no_run():
+            rows(None)
 
-    @pl.when(j + 1 < pl.num_programs(0))
-    def _next():
-        fetch(j + 1, (j + 1) % 2)
+        # a block longer than the table holds no run (``block_runs``)
+        for s in range(n_s if block <= x_hbm.shape[0] else 0):
+            @pl.when(run == s)
+            def _run(s=s):
+                pltpu.make_async_copy(x_hbm.at[pl.ds(start, block)],
+                                      buf.at[slot, s], sem.at[slot]).start()
+                rows(s)
+
+    # blocks j (first step only) and j + 1 (all but the last), from one
+    # call site: each copy of ``fetch`` holds S + 1 issue loops
+    def issue(blk, carry):
+        fetch(blk, blk % 2)
+        return carry
+
+    jax.lax.fori_loop(jnp.where(j == 0, 0, j + 1),
+                      jnp.minimum(j + 2, pl.num_programs(0)), issue, 0)
 
     slot = j % 2
-    # one wait for the whole buffer: the row DMAs into it add up to its size
+    # one wait for the whole buffer: the DMAs into it add up to its size
     pltpu.make_async_copy(buf.at[slot], buf.at[slot], sem.at[slot]).wait()
     wts = wts_ref[...]
     z = jnp.zeros((block, f), jnp.float32)
@@ -192,25 +219,27 @@ def fused_ideal_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
     x: [N, F]; neighbors/weights: [Nd, S]; w: [F, H]; b: [H].
     Returns [Nd, H] float32. Z never touches HBM. Each chunk of
     destination rows runs as ``block_rows`` blocks, zero-weight rows
-    padding the last.
+    padding the last; a block's run slot, if it has one, is fetched in one
+    DMA (``_gather.block_runs``).
     """
     interpret = resolve_interpret(interpret)
     n, f = x.shape
-    n_s = neighbors.shape[1]
+    nd, n_s = neighbors.shape
     f2, h = w.shape
     assert f == f2, (x.shape, w.shape)
     x_rows = rows_view(x)
     w = w.astype(jnp.float32)
     b = b.astype(jnp.float32).reshape(1, h)
+    rows = chunk_rows(nd, n_s)
+    block = block_rows(rows, n_s, f)
+    padded = -(-rows // block) * block      # weight-0 rows, dropped below
+    nbr, wts = (chunk_tables(t, rows, padded)
+                for t in (neighbors, weights.astype(jnp.float32)))
+    runs = block_runs(nbr, block, n)        # [chunks, blocks, 2], one pass
 
-    def call(nbr, wts):
-        rows = nbr.shape[0] // n_s
-        block = block_rows(rows, n_s, f)
-        padded = -(-rows // block) * block  # weight-0 rows, dropped below
-        pad = (0, (padded - rows) * n_s)
-        nbr, wts = jnp.pad(nbr, pad), jnp.pad(wts, pad)
+    def call(nbr, wts, runs):
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,          # neighbors (flat)
+            num_scalar_prefetch=2,          # neighbors, runs (flat)
             grid=(padded // block,),
             in_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),            # X in HBM
@@ -234,13 +263,13 @@ def fused_ideal_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
             interpret=interpret, name="fused_ideal_layer",
             metadata=gather_metadata("fused_ideal_layer", padded, n_s, f, h,
                                      block_rows=block),
-        )(nbr, x_rows, wts.reshape(padded, n_s), w, b)
+        )(nbr.reshape(-1), runs.reshape(-1), x_rows, wts, w, b)
         # Without the barrier XLA fuses the chunk's write into the stacked
         # output with the launch, and a profile then shows a fusion that
         # carries neither the custom call nor its kernel_metadata.
         return jax.lax.optimization_barrier(out)[:rows]
 
-    return map_row_chunks(call, neighbors, weights)
+    return map_chunks(call, nbr, wts, runs).reshape(-1, h)[:nd]
 
 
 @functools.partial(jax.jit, static_argnames="interpret")

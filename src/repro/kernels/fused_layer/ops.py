@@ -22,7 +22,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from repro.kernels._gather import gather_dmas
 from repro.kernels.crossbar_mvm.ref import (CrossbarNumerics,
                                             apply_conductance_noise,
                                             quantize_weights)
@@ -43,20 +45,31 @@ def _pad_rows(a: jax.Array, to: int) -> jax.Array:
     return jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1)) if pad else a
 
 
-def _resolve_bf(x, neighbors, w, cfg, bf, tuned):
+def _resolve_bf(x_shape, nbr_shape, f_out, cfg, bf, tuned):
     """Lane block for this launch: explicit ``bf`` wins, else the tuned
     bundle, else the process tuning registry, else the 128 default.
     Resolution is eager (outside the jitted impl); callers inside an outer
     jit thread ``tuned`` so the decision is part of the jit key."""
     if bf is not None:
         return bf
-    geom = FusedGeometry(nd=neighbors.shape[0], n=x.shape[0],
-                         f_in=x.shape[1], f_out=w.shape[1],
-                         sample=neighbors.shape[1], ideal=cfg.ideal,
+    geom = FusedGeometry(nd=nbr_shape[0], n=x_shape[0], f_in=x_shape[1],
+                         f_out=f_out, sample=nbr_shape[1], ideal=cfg.ideal,
                          rows_per_xbar=cfg.rows_per_xbar)
     c = ((tuned.lookup(geom.key()) if tuned is not None else None)
          or _tuning_registry.lookup(geom.key()))
     return c.bf if c else 128
+
+
+def ideal_layer_dmas(neighbors: np.ndarray, n: int, f_in: int, f_out: int,
+                     tuned=None) -> tuple:
+    """``(row DMAs, block DMAs)`` of the ``fused_ideal_layer`` launch that
+    ``fused_gnn_layer`` makes with ideal numerics over an ``[n, f_in]``
+    table and the ``[Nd, S]`` sample ``neighbors``: the lane padding the
+    launch gets, then ``_gather.gather_dmas``."""
+    cfg = CrossbarNumerics(ideal=True)
+    bf = _resolve_bf((n, f_in), neighbors.shape, f_out, cfg, None, tuned)
+    k_pad = padded_grid(n, f_in, f_out, bf, bm=1, bn=bf).k_pad
+    return gather_dmas(neighbors, n, k_pad)
 
 
 def fused_gnn_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
@@ -76,7 +89,7 @@ def fused_gnn_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
     conductance-code perturbation on the programmed weights
     (``devices.variation``) — ignored on the ideal path.
     """
-    bf = _resolve_bf(x, neighbors, w, cfg, bf, tuned)
+    bf = _resolve_bf(x.shape, neighbors.shape, w.shape[1], cfg, bf, tuned)
     return _fused_gnn_layer(x, neighbors, weights, w, b, cfg, relu=relu,
                             bf=bf, interpret=interpret, w_noise=w_noise)
 

@@ -21,10 +21,15 @@ boundary instead:
      and ``send_rows`` (rows to each peer, padded), and ``launched_bytes``
      (what each chip's collective sends in one forward, padding and the
      self block included). ``halo.exchange`` keeps the true bytes, summed
-     over the chips; ``chips x launched_bytes`` less them is the padding.
+     over the chips; ``chips x launched_bytes`` less them is the padding;
+  5. on the fused backend with ideal numerics, states on ``plan.forward``
+     the DMAs the ``fused_ideal_layer`` launches issue in one forward,
+     summed over layers and chips: ``row_dmas``, one a (row, slot), and
+     ``block_dmas``, one for each block whose slot is a run of
+     consecutive table rows (``ExecutionPlan.gather_dmas``).
 
-The traffic report is computed lazily on the first *traced* call and
-cached — with telemetry disabled the wrapper is a flag check plus the
+The traffic report and the DMA counts are computed lazily on the first
+*traced* call and cached — with telemetry disabled the wrapper is a flag check plus the
 undecorated forward.
 """
 
@@ -56,6 +61,7 @@ def instrument_forward(plan, cfg, mode: str, fwd: Callable,
                              send_rows=rep.send_rows,
                              launched_bytes=rep.launched_bytes())
                         if spmd else {})
+            launched.update(plan.gather_dmas(cfg))
             billing = state["billing"] = (tier0, per_layer,
                                           tier0 + sum(per_layer), launched)
         tier0, per_layer, total, launched = billing

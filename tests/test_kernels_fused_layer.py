@@ -14,7 +14,8 @@ import jax.numpy as jnp
 
 from _hyp import given, settings, st
 from repro.core import gnn, random_graph
-from repro.kernels._gather import block_rows
+from repro.kernels._gather import (block_rows, block_runs, chunk_rows,
+                                  chunk_tables, gather_dmas)
 from repro.kernels.crossbar_mvm import CrossbarNumerics
 from repro.kernels.fused_layer import (fused_gnn_forward,
                                        fused_gnn_forward_batched,
@@ -80,15 +81,97 @@ def test_block_rows(rows, slots, width, block):
     assert block_rows(rows, slots, width) == block
 
 
+# tables whose slots hold runs of consecutive rows: a self loop names the
+# destination row's own table row, so their tables hold at least nd rows
+RUN_TABLES = ("self", "self-ragged", "self-broken", "two-runs", "self-tail")
+
+
 def _block_case(n, f, h, nd, s, seed, tables="random"):
+    if tables in RUN_TABLES:
+        n = max(n, nd)
     x, nbr, wts, w, b = _case(n, f, h, nd, s, seed=seed)
+    rng = np.random.default_rng(seed)
+    rows = np.arange(nd, dtype=np.int32)
     if tables == "padded":     # weight-0 padding slots naming row 0
-        pad = np.random.default_rng(seed).random((nd, s)) < 0.3
+        pad = rng.random((nd, s)) < 0.3
         nbr = jnp.where(pad, 0, nbr)
         wts = jnp.where(pad, 0.0, wts)
     if tables == "repeated":   # one source row named by half the slots
         nbr = nbr.at[:, : (s + 1) // 2].set(3 % n)
+    if tables in ("self", "self-broken", "two-runs"):
+        nbr = nbr.at[:, -1].set(rows)   # the self loop last, as built
+    if tables == "self-broken":         # one row breaks its block's run
+        nbr = nbr.at[nd // 2, -1].set((nd // 2 + 1) % n)
+    if tables == "two-runs":            # a second run, which wraps once
+        nbr = nbr.at[:, 1].set((rows + nd // 3) % n)
+    if tables == "self-tail":           # the run ends at the table's end
+        nbr = nbr.at[:, -1].set(rows + n - nd)
+    if tables == "self-ragged":
+        # the self loop at slot min(deg, S - 1), padding after it: rows of
+        # degree under S - 1 in the first half only, so some blocks are
+        # runs and some are not
+        deg = np.full(nd, s - 1)
+        low = (rows < nd // 2) & (rng.random(nd) < 0.05)
+        deg[low] = rng.integers(0, s - 1, int(low.sum()))
+        slot = np.arange(s)[None, :]
+        t = np.where(slot < deg[:, None], np.asarray(nbr), 0)
+        t[rows, deg] = rows
+        nbr = jnp.asarray(t)
+        wts = jnp.where(jnp.asarray(slot <= deg[:, None]), wts, 0.0)
     return x, nbr, wts, w, b
+
+
+@pytest.mark.parametrize("nd,s,f,tables", [
+    (300, 8, 1024, "random"), (304, 8, 1024, "self"),
+    (300, 8, 1024, "self"), (300, 8, 1024, "self-ragged"),
+    (300, 8, 1024, "self-broken"), (300, 8, 1024, "two-runs"),
+    (288, 8, 1024, "self-tail"), (4500, 8, 128, "self"),
+    (300, 8, 1024, "padded")])
+def test_block_runs_numpy_matches_jnp(nd, s, f, tables):
+    """The run rule gives the same table on numpy and jnp, finds the runs
+    each kind of table was built to hold, and ``gather_dmas`` counts one
+    block DMA for each in place of its rows' DMAs."""
+    x, nbr, *_ = _block_case(300, f, 8, nd, s, nd, tables)
+    n = x.shape[0]
+    rows = chunk_rows(nd, s)
+    block = block_rows(rows, s, f)
+    padded = -(-rows // block) * block
+    t = chunk_tables(np.asarray(nbr), rows, padded)
+    runs = block_runs(t, block, n)
+    np.testing.assert_array_equal(
+        np.asarray(block_runs(chunk_tables(nbr, rows, padded), block, n)),
+        runs)
+    slot, start = runs[..., 0], runs[..., 1]
+    c, j = np.indices(slot.shape)
+    end = (j + 1) * block
+    full = (end <= rows) & (c * rows + end <= nd)   # no padding row
+    if tables in ("random", "padded"):
+        assert (slot == -1).all()
+    if tables in ("self", "self-tail"):
+        np.testing.assert_array_equal(slot, np.where(full, s - 1, -1))
+    if tables == "self-tail":
+        assert start[full].max() + block == n
+    if tables == "self-ragged":
+        assert 0 < (slot >= 0).sum() < full.sum()
+    if tables == "self-broken":
+        assert (slot >= 0).sum() == full.sum() - 1
+    if tables == "two-runs":            # the first run slot is taken
+        assert set(slot[full].tolist()) == {1, s - 1}
+    n_runs = int((slot >= 0).sum())
+    assert gather_dmas(np.asarray(nbr), n, f) == (t.size - n_runs * block,
+                                                  n_runs)
+
+
+def test_gather_dmas_collab_chunking():
+    """A self loop in the last slot of every row: one block DMA per block
+    with no padding row, for each width's block size."""
+    nd, s, n = 9000, 8, 9000
+    nbr = np.zeros((nd, s), np.int32)
+    nbr[:, -1] = np.arange(nd)
+    # 3 chunks of 4,096 rows: 512-lane rows take blocks of 256, 128-lane
+    # rows blocks of 1,024; the last chunk holds 808 rows
+    assert gather_dmas(nbr, n, 496) == (3 * 4096 * s - 35 * 256, 35)
+    assert gather_dmas(nbr, n, 64) == (3 * 4096 * s - 8 * 1024, 8)
 
 
 @pytest.mark.parametrize("n,f,h,nd,s,tables", [
@@ -100,6 +183,13 @@ def _block_case(n, f, h, nd, s, seed, tables="random"):
     (4, 128, 16, 24, 6, "random"),       # four rows for all slots of a block
     (60, 128, 32, 70, 8, "padded"),      # weight-0 padding slots
     (30, 128, 128, 17, 1, "random"),     # S = 1
+    (304, 1024, 16, 304, 8, "self"),     # the self loop: every block a run
+    (300, 1024, 16, 300, 8, "self"),     # a padded last block: no run
+    (300, 1024, 16, 300, 8, "self-ragged"),  # runs in some blocks only
+    (300, 1024, 16, 300, 8, "self-broken"),  # one row breaks one run
+    (300, 1024, 16, 300, 8, "two-runs"),     # runs in two slots
+    (300, 1024, 16, 288, 8, "self-tail"),    # a run ends at the last row
+    (300, 128, 32, 4500, 8, "self"),     # runs in more than one chunk
 ])
 def test_block_gather_matches_ref(n, f, h, nd, s, tables):
     x, nbr, wts, w, b = _block_case(n, f, h, nd, s, n + nd, tables)
@@ -120,7 +210,10 @@ def _slot_order_layer(x, nbr, wts, w):
 
 
 @pytest.mark.parametrize("nd,s,tables", [
-    (203, 8, "random"), (4500, 8, "padded"), (9, 3, "repeated")])
+    (203, 8, "random"), (4500, 8, "padded"), (9, 3, "repeated"),
+    (2048, 8, "self"), (2040, 8, "self"), (2048, 8, "self-ragged"),
+    (2048, 8, "self-broken"), (2048, 8, "two-runs"),
+    (2048, 8, "self-tail")])
 def test_block_gather_z_bit_exact(nd, s, tables):
     """Z is each row's float32 sum over its slots in slot order, bit for
     bit: with W = I and no bias the layer returns Z itself. The reference

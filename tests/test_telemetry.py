@@ -230,6 +230,82 @@ def test_disabled_forward_is_undecorated(make_graph):
     np.testing.assert_array_equal(off, on)
 
 
+# ---- plan.forward's DMA counts of the fused kernel ----------------------
+
+def _fused_plan(setting="centralized"):
+    """A fused plan over 64 nodes of in-degree 3 and a 4-slot sample: the
+    self loop fills slot 3 of every row, so each block of rows is a run."""
+    from repro.core.graph import Graph
+    from repro.core.partition import plan_execution
+    n, d = 64, 3
+    rng = np.random.default_rng(0)
+    g = Graph(np.arange(n + 1, dtype=np.int64) * d,
+              rng.integers(0, n, n * d).astype(np.int32), None,
+              rng.normal(size=(n, 8)).astype(np.float32)).gcn_normalize()
+    return plan_execution(g, setting, backend="fused", sample=d + 1,
+                          n_clusters=None if setting == "centralized" else 2)
+
+
+def _forward_attrs(plan, cfg):
+    import jax
+    from repro.core import gnn
+    params = gnn.init_params(jax.random.key(0), plan.gnn_config(cfg))
+    jax.block_until_ready(plan.make_forward(cfg)(params))
+    return [r.attrs for r in tel.get_tracer().roots
+            if r.name == "plan.forward"]
+
+
+@pytest.mark.parametrize("tables", ["self", "random"])
+def test_forward_span_states_gather_dmas(tables):
+    """A centralized fused plan's ``plan.forward`` states the DMAs of its
+    ``fused_ideal_layer`` launches, summed over layers, as the helper
+    counts them on the plan's sample: one block DMA a layer where the self
+    loop is a run, none for a sample with no run."""
+    from repro.core import gnn
+    from repro.kernels.fused_layer import ideal_layer_dmas
+    plan = _fused_plan()
+    if tables == "random":
+        rng = np.random.default_rng(1)
+        plan.neighbors = rng.integers(0, 64, plan.neighbors.shape
+                                      ).astype(np.int32)
+    cfg = gnn.GNNConfig(in_dim=8, hidden_dims=(8,), out_dim=4, sample=4)
+    tel.enable()
+    (attrs,) = _forward_attrs(plan, cfg)
+    want = [ideal_layer_dmas(plan.neighbors[0], 64, a, b)
+            for a, b in ((8, 8), (8, 4))]
+    assert attrs["row_dmas"] == sum(r for r, _ in want)
+    assert attrs["block_dmas"] == sum(b for _, b in want)
+    runs = 2 if tables == "self" else 0
+    assert attrs["block_dmas"] == runs
+    assert attrs["row_dmas"] == 2 * 64 * 4 - runs * 64
+
+
+def test_gather_dmas_absent_off_the_fused_kernel(monkeypatch):
+    """No DMA counts with telemetry off (the count is never made), nor on
+    a backend or numerics that launch no ``fused_ideal_layer``."""
+    import dataclasses
+    from repro.core import gnn
+    from repro.core.partition import ExecutionPlan
+    from repro.kernels.crossbar_mvm import CrossbarNumerics
+    cfg = gnn.GNNConfig(in_dim=8, hidden_dims=(8,), out_dim=4, sample=4)
+    quant = CrossbarNumerics(in_bits=8, w_bits=8, adc_bits=12,
+                             rows_per_xbar=64)
+    plan = _fused_plan()
+    calls = []
+    count = ExecutionPlan.gather_dmas
+    monkeypatch.setattr(ExecutionPlan, "gather_dmas",
+                        lambda self, c: calls.append(c) or count(self, c))
+    assert _forward_attrs(plan, cfg) == [] and not calls
+    for backend, numerics in (("fused", quant), ("jnp", cfg.numerics)):
+        tel.reset()
+        tel.enable()
+        plan.backend = backend
+        (attrs,) = _forward_attrs(
+            plan, dataclasses.replace(cfg, numerics=numerics))
+        assert not {"row_dmas", "block_dmas"} & set(attrs)
+    assert len(calls) == 2
+
+
 # ---- streaming server: observer isolation + counters --------------------
 
 def _tiny_server(make_graph, policy="eager"):
