@@ -15,6 +15,12 @@ Backends (``GNNConfig.backend``):
   * ``fused``  — both stages in one ``fused_gnn_layer`` kernel launch: Z
     stays resident in VMEM between aggregation and feature extraction
     (DESIGN.md §5). Inference/serving only — the fused kernel has no VJP.
+
+``layer_step`` is the one GNN layer of the repo: the only code that
+chooses between aggregate + transform and ``fused_gnn_layer``. Every
+placement runs it — ``forward`` here loops it over the layers, the
+decentralized and semi runtimes (``distributed.halo._layers``) loop it over
+owned plus halo rows, and the streaming engine runs it on dirty rows.
 """
 from __future__ import annotations
 
@@ -63,35 +69,47 @@ def init_params(key: jax.Array, cfg: GNNConfig) -> list:
     return params
 
 
-def _transform(z: jax.Array, w: jax.Array, cfg: GNNConfig) -> jax.Array:
+def layer_step(table: jax.Array, nbr: jax.Array, wts: jax.Array,
+               layer: dict, cfg: GNNConfig, act: bool, *,
+               w_noise: jax.Array | None = None,
+               interpret: bool | None = None) -> jax.Array:
+    """One GNN layer, ``act((A_hat @ table) @ W + b)``, on ``cfg.backend``.
+
+    table: [N, F] feature rows the sample indexes (owned rows, then any
+    halo rows); nbr/wts: [Nd, S] padded sample. ``cfg.numerics`` holds on
+    every backend. ``w_noise``: optional [F, H] conductance-code
+    perturbation of the programmed weights (``devices.variation``),
+    ignored with ideal numerics; ``interpret`` forces the Pallas kernels'
+    mode."""
+    if cfg.backend == "fused":
+        return fused_gnn_layer(table, nbr, wts, layer["w"], layer["b"],
+                               cfg.numerics, relu=act, tuned=cfg.tuned,
+                               interpret=interpret, w_noise=w_noise)
+    z = aggregate(table, nbr, wts, backend=cfg.backend, tuned=cfg.tuned,
+                  interpret=interpret)                  # message + agg
     if cfg.numerics.ideal:
-        return jnp.dot(z, w, preferred_element_type=jnp.float32)
-    return crossbar_matmul_signed_ref(z, w, cfg.numerics)
+        h = jnp.dot(z, layer["w"], preferred_element_type=jnp.float32)
+    else:
+        h = crossbar_matmul_signed_ref(z, layer["w"], cfg.numerics,
+                                       w_noise=w_noise)
+    h = h + layer["b"]
+    return jax.nn.relu(h) if act else h
 
 
 @partial(jax.jit, static_argnames="cfg")
 def forward(params: list, x: jax.Array, neighbors: jax.Array,
             weights: jax.Array, cfg: GNNConfig) -> jax.Array:
-    """Full-graph GNN forward.
+    """Full-graph GNN forward: ``layer_step`` per layer.
 
     x: [N, F_in]; neighbors/weights: [N, S] padded sample (self loops should
     be included in the sample). Returns [N, out_dim] embeddings/logits.
     """
     assert cfg.backend in BACKENDS, cfg.backend
-    h = x
     n_layers = len(params)
     for i, layer in enumerate(params):
-        act = i < n_layers - 1 or cfg.final_activation
-        if cfg.backend == "fused":
-            h = fused_gnn_layer(h, neighbors, weights, layer["w"],
-                                layer["b"], cfg.numerics, relu=act,
-                                tuned=cfg.tuned)
-            continue
-        z = aggregate(h, neighbors, weights, backend=cfg.backend)  # message+agg
-        h = _transform(z, layer["w"], cfg) + layer["b"]
-        if act:
-            h = jax.nn.relu(h)
-    return h
+        x = layer_step(x, neighbors, weights, layer, cfg,
+                       i < n_layers - 1 or cfg.final_activation)
+    return x
 
 
 @partial(jax.jit, static_argnames="cfg")

@@ -317,6 +317,16 @@ class BucketedPartition:
     def dense_padded_rows(self) -> int:
         return self.part.n_clusters * self.part.n_max
 
+    def row_offsets(self) -> np.ndarray:
+        """[K] first row of each cluster in the buckets' owned tables
+        flattened and concatenated in bucket order (``halo._flat_rows``)."""
+        offset = np.zeros(self.part.n_clusters, np.int64)
+        base = 0
+        for cl, cap in zip(self.clusters, self.n_caps):
+            offset[cl] = base + np.arange(len(cl)) * cap
+            base += len(cl) * cap
+        return offset
+
     def padding_ratio(self) -> float:
         """Padded rows / real rows of the bucketed layout (>= 1)."""
         return self.padded_rows() / max(self.real_rows(), 1)
@@ -697,39 +707,34 @@ class ExecutionPlan:
         """Map per-cluster outputs [K, n_max, D] to global node order.
 
         Bucketed plans pass the forward's tuple of per-bucket
-        ``[K_b, n_cap, D]`` arrays. Spans: ``plan.scatter``, and inside it
+        ``[K_b, n_cap, D]`` arrays. Either way the assembly is one gather
+        by ``_owner_rows``. Spans: ``plan.scatter``, and inside it
         ``plan.scatter.fetch``, the copy from the device to the host; the
         rest is the assembly in global order."""
         with tel.span("plan.scatter"):
-            bucketed = (self.bucketed is not None
-                        and isinstance(out, (list, tuple)))
             with tel.span("plan.scatter.fetch"):
-                out = ([np.asarray(o) for o in out] if bucketed
-                       else np.asarray(out))
-            if bucketed:
-                full = np.zeros((self.graph.n_nodes, out[0].shape[-1]),
-                                out[0].dtype)
-                sizes = self.part.local_mask.sum(axis=1)
-                for b, cl in enumerate(self.bucketed.clusters):
-                    for j, c in enumerate(cl):
-                        m = int(sizes[c])
-                        full[self.part.local_nodes[c, :m]] = out[b][j, :m]
-                return full
-            if self.setting == "centralized":
+                out = ([np.asarray(o) for o in out]
+                       if self.bucketed is not None else np.asarray(out))
+            if self.part is None:                       # dense centralized
                 return out[0]
-            return np.take(out.reshape(-1, out.shape[-1]), self._owner_rows,
-                           axis=0)
+            flat = (np.concatenate([o.reshape(-1, o.shape[-1]) for o in out])
+                    if self.bucketed is not None
+                    else out.reshape(-1, out.shape[-1]))
+            return np.take(flat, self._owner_rows, axis=0)
 
     @functools.cached_property
     def _owner_rows(self) -> np.ndarray:
-        """[N] row of each node in the dense output flattened to
-        ``[K * n_max, D]``: ``scatter`` is then one gather, where a masked
-        scatter per cluster took several times as long on the host."""
+        """[N] row of each node in the output flattened to ``[rows, D]``
+        (dense: ``[K * n_max, D]``; bucketed: each bucket's
+        ``[K_b * n_cap, D]`` in bucket order): ``scatter`` is then one
+        gather, where a masked scatter per cluster took several times as
+        long on the host."""
         k, n_max = self.part.local_nodes.shape
+        offset = (np.arange(k) * n_max if self.bucketed is None
+                  else self.bucketed.row_offsets())
+        c, r = np.nonzero(self.part.local_mask)
         rows = np.zeros(self.graph.n_nodes, np.int64)
-        for c in range(k):
-            m = self.part.local_mask[c]
-            rows[self.part.local_nodes[c][m]] = c * n_max + np.nonzero(m)[0]
+        rows[self.part.local_nodes[c, r]] = offset[c] + r
         return rows
 
     def layout_stats(self, cfg=None) -> dict:

@@ -191,35 +191,22 @@ def noisy_forward(params, x, neighbors, weights, cfg, noise: list,
                   interpret=None):
     """GNN forward with per-layer conductance noise on any backend.
 
-    Mirrors ``core.gnn.forward`` (same layer loop, same activations) with
-    the noise tensors of ``layer_noise`` riding on each layer's programmed
-    weights. ``cfg`` is a ``GNNConfig`` with bit-accurate numerics.
+    ``core.gnn.forward``'s layer loop with the noise tensors of
+    ``layer_noise`` riding on each layer's programmed weights
+    (``gnn.layer_step``'s ``w_noise``). ``cfg`` is a ``GNNConfig`` with
+    bit-accurate numerics.
     """
-    import jax
     import jax.numpy as jnp
-    from repro.kernels.crossbar_mvm import crossbar_matmul_signed_ref
-    from repro.kernels.csr_aggregate import aggregate
-    from repro.kernels.fused_layer import fused_gnn_layer
+    from repro.core import gnn
     assert not cfg.numerics.ideal, \
         "conductance noise models the bit-accurate path only"
-    h = x
     n_layers = len(params)
     for i, layer in enumerate(params):
-        nz = None if noise[i] is None else jnp.asarray(noise[i])
-        act = i < n_layers - 1 or cfg.final_activation
-        if cfg.backend == "fused":
-            h = fused_gnn_layer(h, neighbors, weights, layer["w"],
-                                layer["b"], cfg.numerics, relu=act,
-                                tuned=cfg.tuned, interpret=interpret,
-                                w_noise=nz)
-            continue
-        z = aggregate(h, neighbors, weights, backend=cfg.backend,
-                      interpret=interpret)
-        h = crossbar_matmul_signed_ref(z, layer["w"], cfg.numerics,
-                                       w_noise=nz) + layer["b"]
-        if act:
-            h = jax.nn.relu(h)
-    return h
+        x = gnn.layer_step(
+            x, neighbors, weights, layer, cfg,
+            i < n_layers - 1 or cfg.final_activation, interpret=interpret,
+            w_noise=None if noise[i] is None else jnp.asarray(noise[i]))
+    return x
 
 
 def accuracy_bounds(tech, dataset: str = "taxi", scale: float = 0.02,

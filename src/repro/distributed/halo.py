@@ -27,11 +27,12 @@ exchange over a ``HierPartition``:
   * tier 1 — head<->head boundary halo per layer, identical machinery to the
     decentralized exchange but over the region-level partition.
 
-The per-device layer honors ``cfg.backend``: the composed ``jnp``/``pallas``
-paths run aggregation then the feature transform, ``fused`` runs both stages
-in one ``fused_gnn_layer`` kernel launch with Z resident in VMEM (so the
-decentralized and semi-decentralized settings get the same HBM-traffic win
-as the centralized path — DESIGN.md §5).
+The layer is ``core.gnn.layer_step``, the same one the centralized forward
+runs, so every ``cfg.backend`` and numerics holds here too (``fused`` keeps
+Z resident in VMEM on each device — DESIGN.md §5). ``_layers`` is the one
+layer loop of the SPMD, emulated and semi forwards: per layer, fetch the
+halo, append it to the owned rows, step. ``_per_cluster`` maps the step over
+the leading cluster axis of the emulated runtime and of a bucket.
 
 Where the exchange tables live: the SPMD forwards (``SpmdForward``) take
 them as arguments of the jitted program, each split over the mesh's
@@ -58,11 +59,9 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro import telemetry as tel
+from repro.core.gnn import layer_step
 from repro.core.partition import (BucketedPartition, HierPartition,
                                   Partition)
-from repro.kernels.crossbar_mvm import crossbar_matmul_signed_ref
-from repro.kernels.csr_aggregate import aggregate, csr_aggregate_ref
-from repro.kernels.fused_layer import fused_gnn_layer
 
 EXCHANGE_MODES = ("allgather", "alltoall")
 OVERLAP_MODES = ("overlap", "serial")
@@ -133,21 +132,23 @@ def _exchange_alltoall(x_own, send_slot, send_mask, recv_to_halo, recv_mask,
     return halo.at[flat_idx].add(flat * recv_mask.reshape(-1)[:, None])
 
 
-def _layer_step(table, nbr, wts, layer, cfg, act: bool):
-    """One GNN layer on a device-local feature table, backend-dispatched.
-    Honors cfg.numerics on every backend (same contract as core.gnn)."""
-    if cfg.backend == "fused":
-        return fused_gnn_layer(table, nbr, wts, layer["w"], layer["b"],
-                               cfg.numerics, relu=act, tuned=cfg.tuned)
-    z = (csr_aggregate_ref(table, nbr, wts) if cfg.backend == "jnp"
-         else aggregate(table, nbr, wts, backend=cfg.backend,
-                        tuned=cfg.tuned))
-    if cfg.numerics.ideal:
-        x = jnp.dot(z, layer["w"], preferred_element_type=jnp.float32)
-    else:
-        x = crossbar_matmul_signed_ref(z, layer["w"], cfg.numerics)
-    x = x + layer["b"]
-    return jax.nn.relu(x) if act else x
+def _per_cluster(table, nbr, wts, layer, cfg, act: bool):
+    """``layer_step`` on each cluster of stacked ``[K, ...]`` tables."""
+    return jnp.stack([layer_step(table[c], nbr[c], wts[c], layer, cfg, act)
+                      for c in range(table.shape[0])])
+
+
+def _layers(params, x, nbr, wts, cfg, exchange, step):
+    """The layer loop of every exchanged placement: per layer, append the
+    halo ``exchange(x)`` to the owned rows ``x`` and run ``step``
+    (``layer_step`` on one device, ``_per_cluster`` on stacked clusters)
+    over the table. Owned and halo rows meet on axis -2 either way."""
+    n_layers = len(params)
+    for i, layer in enumerate(params):
+        table = jnp.concatenate([x, exchange(x)], axis=-2)
+        x = step(table, nbr, wts, layer, cfg,
+                 i < n_layers - 1 or cfg.final_activation)
+    return x
 
 
 # the tables each exchange mode reads
@@ -219,21 +220,18 @@ def _spmd_forward(mesh, device_fn, tables: dict, axis: str) -> SpmdForward:
 
 
 def _spmd_layers(params, x, nbr, wts, cfg, t, mode, h_max, axis):
-    """Per-device layer loop shared by the decentralized and semi SPMD
-    forwards. ``t``: per-device exchange tables (leading axis stripped)."""
-    n_layers = len(params)
-    for i, layer in enumerate(params):
-        if mode == "allgather":
-            halo = _exchange_allgather(x, t["src_c"], t["src_s"],
+    """Per-device layer loop of the decentralized and semi SPMD forwards.
+    ``t``: per-device exchange tables (leading axis stripped)."""
+    if mode == "allgather":
+        def exchange(x):
+            return _exchange_allgather(x, t["src_c"], t["src_s"],
                                        t["hmask"], axis)
-        else:
-            halo = _exchange_alltoall(x, t["send_slot"], t["send_mask"],
+    else:
+        def exchange(x):
+            return _exchange_alltoall(x, t["send_slot"], t["send_mask"],
                                       t["recv_to_halo"], t["recv_mask"],
                                       h_max, axis)
-        table = jnp.concatenate([x, halo], axis=0)      # [n_max+h_max, F]
-        act = i < n_layers - 1 or cfg.final_activation
-        x = _layer_step(table, nbr, wts, layer, cfg, act)
-    return x
+    return _layers(params, x, nbr, wts, cfg, exchange, layer_step)
 
 
 def make_decentralized_forward(mesh, cfg, plan: HaloPlan, n_max: int,
@@ -281,16 +279,9 @@ def _emulated_exchange(x, t, mode, h_max):
 
 
 def _emulated_layers(params, x, nbr, wts, cfg, t, mode, h_max):
-    k = x.shape[0]
-    n_layers = len(params)
-    for i, layer in enumerate(params):
-        halo = _emulated_exchange(x, t, mode, h_max)    # [K, h_max, F]
-        table = jnp.concatenate([x, halo], axis=1)      # [K, n_max+h_max, F]
-        act = i < n_layers - 1 or cfg.final_activation
-        x = jnp.stack([
-            _layer_step(table[c], nbr[c], wts[c], layer, cfg, act)
-            for c in range(k)])
-    return x
+    return _layers(params, x, nbr, wts, cfg,
+                   lambda x: _emulated_exchange(x, t, mode, h_max),
+                   _per_cluster)
 
 
 def make_emulated_forward(cfg, plan: HaloPlan, mode: str = "allgather"):
@@ -379,7 +370,8 @@ class BucketedHaloPlan:
 
     The exchange is realized as ONE gather per destination bucket out of a
     *flat* table concatenating every bucket's owned rows
-    (``cluster_offset[c] = bucket base + index_in[c] * n_cap``): ragged
+    (``BucketedPartition.row_offsets``: bucket base + index_in[c] * n_cap,
+    the order ``ExecutionPlan.scatter`` reads the outputs in): ragged
     per-bucket shapes stay out of the gather indices, and each bucket's
     fetch is an independent launch the scheduler can overlap with another
     bucket's layer step. Wire-level billing stays on the dense partition's
@@ -390,7 +382,6 @@ class BucketedHaloPlan:
     halo_mask: tuple      # per bucket [K_b, h_cap] float32
     n_caps: tuple
     h_caps: tuple
-    flat_rows: int        # total rows of the concatenated owned table
 
     @property
     def n_buckets(self) -> int:
@@ -399,14 +390,8 @@ class BucketedHaloPlan:
 
 def build_bucketed_halo_plan(bpart: BucketedPartition) -> BucketedHaloPlan:
     from repro.core.partition import halo_exchange_tables
-    part = bpart.part
-    src_c, src_s, mask = halo_exchange_tables(part)
-    offset = np.zeros(part.n_clusters, np.int64)
-    base = 0
-    for b, cl in enumerate(bpart.clusters):
-        for j, c in enumerate(cl):
-            offset[c] = base + j * bpart.n_caps[b]
-        base += len(cl) * bpart.n_caps[b]
+    src_c, src_s, mask = halo_exchange_tables(bpart.part)
+    offset = bpart.row_offsets()
     hcount = mask.sum(axis=1)
     fsrc, fmask = [], []
     for b, cl in enumerate(bpart.clusters):
@@ -420,7 +405,7 @@ def build_bucketed_halo_plan(bpart: BucketedPartition) -> BucketedHaloPlan:
         fsrc.append(fs)
         fmask.append(fm)
     return BucketedHaloPlan(tuple(fsrc), tuple(fmask), bpart.n_caps,
-                            bpart.h_caps, base)
+                            bpart.h_caps)
 
 
 @jax.jit
@@ -438,7 +423,7 @@ def _gather_halo(flat, idx, mask):
 
 
 @partial(jax.jit, static_argnames=("cfg", "act"))
-def _bucket_layer(x, halo, nbr, wts, w, b, *, cfg, act):
+def _bucket_layer(x, halo, nbr, wts, layer, *, cfg, act):
     """One GNN layer over one bucket [K_b, n_cap(+h_cap), ...].
 
     The halo buffer is freshly allocated per layer by ``_gather_halo`` and
@@ -447,11 +432,8 @@ def _bucket_layer(x, halo, nbr, wts, w, b, *, cfg, act):
     kills per-tick host round-trips lives on the streaming engine's
     same-shape activation-cache scatters (DESIGN.md §12). The owned table
     ``x`` is never donated — callers hold it across repeated calls."""
-    layer = {"w": w, "b": b}
-    table = jnp.concatenate([x, halo], axis=1)
-    return jnp.stack([
-        _layer_step(table[c], nbr[c], wts[c], layer, cfg, act)
-        for c in range(x.shape[0])])
+    return _per_cluster(jnp.concatenate([x, halo], axis=1), nbr, wts,
+                        layer, cfg, act)
 
 
 def make_emulated_bucketed_forward(cfg, bplan: BucketedHaloPlan,
@@ -484,33 +466,27 @@ def make_emulated_bucketed_forward(cfg, bplan: BucketedHaloPlan,
         # same with telemetry on or off; the device's time per bucket is
         # in the device trace of a profile, on the spans' clock.
         tracer = tel.get_tracer()
-        xs = list(feats)
+        xs = tuple(feats)
         n_layers = len(params)
         for i, layer in enumerate(params):
             act = i < n_layers - 1 or cfg.final_activation
             flat = _flat_rows(*xs)
+
+            def gather(b):
+                with tracer.span("halo.gather", layer=i, bucket=b):
+                    return _gather_halo(flat, fidx[b], fmask[b])
+
+            def step(b, halo):
+                with tracer.span("halo.mvm", layer=i, bucket=b):
+                    return _bucket_layer(xs[b], halo, nbrs[b], wtss[b],
+                                         layer, cfg=cfg, act=act)
+
             if overlap == "overlap":
-                halos = []
-                for b in range(nb):
-                    with tracer.span("halo.gather", layer=i, bucket=b):
-                        halos.append(_gather_halo(flat, fidx[b], fmask[b]))
-                xs_next = []
-                for b in range(nb):
-                    with tracer.span("halo.mvm", layer=i, bucket=b):
-                        xs_next.append(
-                            _bucket_layer(xs[b], halos[b], nbrs[b], wtss[b],
-                                          layer["w"], layer["b"], cfg=cfg,
-                                          act=act))
-                xs = xs_next
+                halos = [gather(b) for b in range(nb)]
+                xs = tuple(step(b, halos[b]) for b in range(nb))
             else:
-                for b in range(nb):
-                    with tracer.span("halo.gather", layer=i, bucket=b):
-                        halo = _gather_halo(flat, fidx[b], fmask[b])
-                    with tracer.span("halo.mvm", layer=i, bucket=b):
-                        xs[b] = _bucket_layer(xs[b], halo, nbrs[b], wtss[b],
-                                              layer["w"], layer["b"],
-                                              cfg=cfg, act=act)
-        return tuple(xs)
+                xs = tuple(step(b, gather(b)) for b in range(nb))
+        return xs
 
     return forward
 

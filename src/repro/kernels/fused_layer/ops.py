@@ -12,9 +12,9 @@ over Z), and dispatches to the right kernel:
     materializations (write + quantize-max read + pos/neg DAC reads) to
     2*Nd floats.
 
-``fused_gnn_forward`` is the multi-layer driver (the full-graph network),
-``fused_gnn_forward_batched`` maps it over a leading cluster/device axis —
-the building block the decentralized/semi serving paths use per device.
+The package ends at one layer: ``core.gnn.layer_step`` calls
+``fused_gnn_layer`` for the ``fused`` backend, and the layer loops of every
+placement live above it (``core.gnn.forward``, ``distributed.halo``).
 """
 from __future__ import annotations
 
@@ -131,49 +131,3 @@ def _fused_gnn_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
     out = fused_quant_layer(xp, neighbors, weights, wqp, bp, scales, cfg,
                             relu=relu, interpret=interpret)
     return out[:, :h]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "final_activation", "bf",
-                                    "interpret"))
-def fused_gnn_forward(params: list, x: jax.Array, neighbors: jax.Array,
-                      weights: jax.Array,
-                      cfg: CrossbarNumerics = CrossbarNumerics(ideal=True),
-                      *, final_activation: bool = False, bf: int = 128,
-                      interpret: bool | None = None) -> jax.Array:
-    """Multi-layer fused driver: the full-graph GNN forward, one fused
-    kernel launch per layer (plus the scale pass on the bit-accurate path).
-
-    params: [{'w': [F_i, F_i+1], 'b': [F_i+1]}, ...]; x: [N, F_0];
-    neighbors/weights: [N, S]. Semantics match ``repro.core.gnn.forward``.
-    """
-    h = x
-    n_layers = len(params)
-    for i, layer in enumerate(params):
-        relu = i < n_layers - 1 or final_activation
-        h = fused_gnn_layer(h, neighbors, weights, layer["w"], layer["b"],
-                            cfg, relu=relu, bf=bf, interpret=interpret)
-    return h
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "final_activation", "bf",
-                                    "interpret"))
-def fused_gnn_forward_batched(params: list, x: jax.Array,
-                              neighbors: jax.Array, weights: jax.Array,
-                              cfg: CrossbarNumerics = CrossbarNumerics(
-                                  ideal=True),
-                              *, final_activation: bool = False,
-                              bf: int = 128,
-                              interpret: bool | None = None) -> jax.Array:
-    """Batched multi-layer driver over a leading cluster/device axis.
-
-    x: [K, N, F]; neighbors/weights: [K, N, S]. Each cluster runs the fused
-    multi-layer forward on its own subgraph (static unroll — K is the
-    partition fan-out, small by construction). Returns [K, N, out_dim].
-    """
-    outs = [fused_gnn_forward(params, x[k], neighbors[k], weights[k], cfg,
-                              final_activation=final_activation, bf=bf,
-                              interpret=interpret)
-            for k in range(x.shape[0])]
-    return jnp.stack(outs)

@@ -14,10 +14,12 @@ any backend) and maintains:
 
 Per tick, ``apply_delta`` commits the mutation buffer, expands the k-hop
 dirty frontier (``streaming.frontier``), and re-runs each layer only on its
-dirty rows — through the exact same per-device layer step
-(``distributed.halo._layer_step``) every backend-setting combination uses,
-so incremental output matches a full recompute to fp32 tolerance (the
-property ``tests/test_streaming.py`` checks on all 3 × 3 combinations).
+dirty rows — through the exact same layer step (``core.gnn.layer_step``)
+every backend-setting combination uses, so incremental output matches a
+full recompute to fp32 tolerance (the property ``tests/test_streaming.py``
+checks on all 3 × 3 combinations). A full refresh runs that step per
+cluster through ``distributed.halo._bucket_layer``, the dense layout as one
+bucket of every cluster, with the halo of ``halo._emulated_exchange``.
 Halo inputs for dirty rows are gathered from the cached level-(l-1) owned
 tables; the wire traffic a real deployment would ship for that gather —
 only rows whose value changed, plus send slots structural churn newly
@@ -45,14 +47,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import telemetry as tel
+from repro.core.gnn import layer_step
 from repro.core.partition import (ExecutionPlan, _from_assignment,
                                   bucket_partition, build_bucketed_subgraphs,
                                   build_local_subgraphs,
                                   gather_bucketed_features)
-from repro.distributed.halo import (HaloPlan, _bucket_layer, _flat_rows,
-                                    _gather_halo, _layer_step,
-                                    build_bucketed_halo_plan,
-                                    build_halo_plan)
+from repro.distributed.halo import (HaloPlan, _bucket_layer,
+                                    _emulated_exchange, _flat_rows,
+                                    _gather_halo, build_bucketed_halo_plan,
+                                    build_halo_plan, exchange_tables)
 from repro.distributed.traffic import (StreamingTrafficReport,
                                        measure_incremental)
 from repro.streaming.delta import DeltaResult, GraphDelta, apply_deltas
@@ -79,10 +82,7 @@ def _pad_rows(rows: np.ndarray, cap: int) -> np.ndarray:
     return padded
 
 
-_rows_step = jax.jit(
-    lambda table, nbr, wts, w, b, cfg, act:
-    _layer_step(table, nbr, wts, {"w": w, "b": b}, cfg, act),
-    static_argnames=("cfg", "act"))
+_rows_step = jax.jit(layer_step, static_argnames=("cfg", "act"))
 
 # the activation-cache patch: the cache buffer is DONATED — the scatter's
 # output aliases the input's pages, so per-tick updates mutate the
@@ -176,16 +176,6 @@ class IncrementalEngine:
             return self.graph.features[None].astype(np.float32)
         return gather_features(self.graph, self.plan.part)
 
-    def _halo_table(self, owned: jax.Array) -> jax.Array | None:
-        """[K, h_max, F] halo rows gathered from the stacked owned tables
-        (the emulated exchange's value semantics; what a real deployment
-        ships to keep this table fresh is billed separately)."""
-        hp = self._halo_plan
-        if hp is None:
-            return None
-        halo = owned[hp.src_cluster, hp.src_slot]
-        return halo * jnp.asarray(hp.halo_mask.astype(np.float32))[..., None]
-
     # ---- full refresh ---------------------------------------------------
 
     def full_refresh(self) -> float:
@@ -199,39 +189,30 @@ class IncrementalEngine:
     def _full_refresh_impl(self) -> float:
         t0 = time.perf_counter()
         nbr, wts = self.plan.neighbors, self.plan.weights
+        hp = self._halo_plan
+        xt = None if hp is None else exchange_tables(hp, "allgather")
         if self._bp is not None:
-            xs = [jnp.asarray(f) for f in self._owned_features()]
-            acts = [xs]
-            nb = self._bp.n_buckets
-            for l in range(self.n_layers):
-                layer = self.params[l]
-                act = l < self.n_layers - 1 or self.cfg.final_activation
+            acts = [[jnp.asarray(f) for f in self._owned_features()]]
+        else:
+            acts = [jnp.asarray(self._owned_features())]
+        for l, layer in enumerate(self.params):
+            act = l < self.n_layers - 1 or self.cfg.final_activation
+            if self._bp is not None:
                 flat = _flat_rows(*acts[l])
                 acts.append([
                     _bucket_layer(acts[l][b],
                                   _gather_halo(flat, self._bfidx[b],
                                                self._bfmask[b]),
                                   jnp.asarray(nbr[b]), jnp.asarray(wts[b]),
-                                  layer["w"], layer["b"], cfg=self.cfg,
-                                  act=act)
-                    for b in range(nb)])
-            jax.block_until_ready(acts[-1])
-            self._acts = acts
-            return time.perf_counter() - t0
-        x = jnp.asarray(self._owned_features())
-        acts = [x]
-        for l in range(self.n_layers):
-            layer = self.params[l]
-            act = l < self.n_layers - 1 or self.cfg.final_activation
-            halo = self._halo_table(acts[l])
-            outs = []
-            for c in range(self._k):
-                table = (acts[l][c] if halo is None
-                         else jnp.concatenate([acts[l][c], halo[c]], axis=0))
-                outs.append(_rows_step(table, jnp.asarray(nbr[c]),
-                                       jnp.asarray(wts[c]), layer["w"],
-                                       layer["b"], self.cfg, act))
-            acts.append(jnp.stack(outs))
+                                  layer, cfg=self.cfg, act=act)
+                    for b in range(self._bp.n_buckets)])
+                continue
+            # a centralized plan reads no halo: zero halo rows
+            halo = (acts[l][:, :0] if xt is None
+                    else _emulated_exchange(acts[l], xt, "allgather", None))
+            acts.append(_bucket_layer(acts[l], halo, jnp.asarray(nbr),
+                                      jnp.asarray(wts), layer, cfg=self.cfg,
+                                      act=act))
         jax.block_until_ready(acts[-1])
         self._acts = acts
         return time.perf_counter() - t0
@@ -451,8 +432,8 @@ class IncrementalEngine:
                 with tracer.span("halo.mvm", layer=l, cluster=c,
                                  rows=len(rows)):
                     out = _rows_step(table, jnp.asarray(sub_nbr),
-                                     jnp.asarray(sub_wts),
-                                     layer["w"], layer["b"], self.cfg, act)
+                                     jnp.asarray(sub_wts), layer, self.cfg,
+                                     act)
                 with tracer.span("cache.scatter", layer=l + 1, cluster=c):
                     self._acts[l + 1] = _scatter_rows(
                         self._acts[l + 1], c, jnp.asarray(padded), out)
@@ -495,8 +476,8 @@ class IncrementalEngine:
                 with tracer.span("halo.mvm", layer=l, bucket=b, cluster=c,
                                  rows=len(rows)):
                     out = _rows_step(table, jnp.asarray(sub_nbr),
-                                     jnp.asarray(sub_wts),
-                                     layer["w"], layer["b"], self.cfg, act)
+                                     jnp.asarray(sub_wts), layer, self.cfg,
+                                     act)
                 with tracer.span("cache.scatter", layer=l + 1, bucket=b):
                     self._acts[l + 1][b] = _scatter_rows(
                         self._acts[l + 1][b], j, jnp.asarray(padded), out)

@@ -15,7 +15,7 @@ from repro.devices import (ANCHOR, CalibrationStaleError, HostCalibration,
                            NOISE_GRID, UnknownTechnologyError,
                            known_technologies, load_calibration,
                            modeled_p99_error, mvm_error_bounds,
-                           primitive_scales, resolve_technology,
+                           noisy_forward, primitive_scales, resolve_technology,
                            sample_conductance_noise, save_calibration,
                            technology_table)
 from repro.devices.params import SOT_MRAM, TechnologyParams
@@ -122,6 +122,24 @@ def test_bounds_seed_deterministic_rerun():
 def test_sram_zero_noise_is_exactly_clean():
     b = mvm_error_bounds("sram", **BOUNDS_KW)
     assert b.mean_err == 0.0 and b.p99_err == 0.0 and b.ci95 == 0.0
+
+
+def test_noisy_forward_without_noise_is_forward(backend, make_graph):
+    """With every layer's noise ``None``, the noisy forward is
+    ``gnn.forward`` to the bit on each backend: both run
+    ``gnn.layer_step`` over the same layer loop."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core import gnn
+    g = make_graph(30, 150, 16, seed=4)
+    nbr, wts = g.neighbor_sample(8)
+    args = (jnp.asarray(g.features), jnp.asarray(nbr), jnp.asarray(wts))
+    cfg = gnn.GNNConfig(in_dim=16, hidden_dims=(24,), out_dim=5, sample=8,
+                        numerics=CrossbarNumerics(), backend=backend)
+    params = gnn.init_params(jax.random.key(2), cfg)
+    want = np.asarray(gnn.forward(params, *args, cfg))
+    got = np.asarray(noisy_forward(params, *args, cfg, [None] * len(params)))
+    np.testing.assert_array_equal(got, want)
 
 
 def test_bounds_monotone_in_sigma():

@@ -13,13 +13,11 @@ import jax
 import jax.numpy as jnp
 
 from _hyp import given, settings, st
-from repro.core import gnn, random_graph
+from repro.core import gnn
 from repro.kernels._gather import (block_rows, block_runs, chunk_rows,
                                   chunk_tables, gather_dmas)
 from repro.kernels.crossbar_mvm import CrossbarNumerics
-from repro.kernels.fused_layer import (fused_gnn_forward,
-                                       fused_gnn_forward_batched,
-                                       fused_gnn_layer, fused_ideal_layer,
+from repro.kernels.fused_layer import (fused_gnn_layer, fused_ideal_layer,
                                        fused_layer_ref)
 
 QUANT = CrossbarNumerics(in_bits=8, w_bits=8, adc_bits=12, rows_per_xbar=64)
@@ -256,24 +254,6 @@ def test_property_fused_composed_equivalence(n, f, h, s, ideal, seed):
     x, nbr, wts, w, b = _case(n, f, h, n, s, seed=seed)
     cfg = CrossbarNumerics(ideal=True) if ideal else QUANT
     _check(x, nbr, wts, w, b, cfg, relu=bool(seed % 2))
-
-
-def test_multilayer_driver_matches_gnn_forward():
-    g = random_graph(40, 200, 24, seed=5).gcn_normalize()
-    cfg = gnn.GNNConfig(in_dim=24, hidden_dims=(32, 16), out_dim=6, sample=8)
-    params = gnn.init_params(jax.random.key(0), cfg)
-    nbr, wts = g.neighbor_sample(8)
-    args = (jnp.asarray(g.features), jnp.asarray(nbr), jnp.asarray(wts))
-    ref = gnn.forward(params, *args, cfg)
-    out = fused_gnn_forward(params, *args, cfg.numerics)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-5)
-    # batched driver: two stacked copies of the same subgraph
-    batched = fused_gnn_forward_batched(
-        params, *(jnp.stack([a, a]) for a in args), cfg.numerics)
-    for k in range(2):
-        np.testing.assert_allclose(np.asarray(batched[k]), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-5)
 
 
 def test_gnn_forward_backend_dispatch(backend, make_graph):
