@@ -21,6 +21,11 @@ chooses between aggregate + transform and ``fused_gnn_layer``. Every
 placement runs it — ``forward`` here loops it over the layers, the
 decentralized and semi runtimes (``distributed.halo._layers``) loop it over
 owned plus halo rows, and the streaming engine runs it on dirty rows.
+
+``fused_operands`` builds what the fused launches of a forward read and no
+update changes — the sample tables and, where it is a plan's own, the
+layer-1 feature table — in the form they read it, once per plan; the
+forwards pass them where the raw tables would go.
 """
 from __future__ import annotations
 
@@ -33,7 +38,8 @@ import jax.numpy as jnp
 
 from repro.kernels.crossbar_mvm import CrossbarNumerics, crossbar_matmul_signed_ref
 from repro.kernels.csr_aggregate import aggregate
-from repro.kernels.fused_layer import fused_gnn_layer
+from repro.kernels.fused_layer import (fused_gnn_layer, ideal_launch,
+                                       prepare_sample, prepare_table)
 
 BACKENDS = ("jnp", "pallas", "fused")
 
@@ -76,7 +82,9 @@ def layer_step(table: jax.Array, nbr: jax.Array, wts: jax.Array,
     """One GNN layer, ``act((A_hat @ table) @ W + b)``, on ``cfg.backend``.
 
     table: [N, F] feature rows the sample indexes (owned rows, then any
-    halo rows); nbr/wts: [Nd, S] padded sample. ``cfg.numerics`` holds on
+    halo rows); nbr/wts: [Nd, S] padded sample. On the ``fused`` backend
+    with ideal numerics either may come as ``fused_operands`` built it.
+    ``cfg.numerics`` holds on
     every backend. ``w_noise``: optional [F, H] conductance-code
     perturbation of the programmed weights (``devices.variation``),
     ignored with ideal numerics; ``interpret`` forces the Pallas kernels'
@@ -94,6 +102,27 @@ def layer_step(table: jax.Array, nbr: jax.Array, wts: jax.Array,
                                        w_noise=w_noise)
     h = h + layer["b"]
     return jax.nn.relu(h) if act else h
+
+
+@partial(jax.jit, static_argnames=("cfg", "n_rows", "table"))
+def fused_operands(feats: jax.Array, neighbors: jax.Array,
+                   weights: jax.Array, cfg: GNNConfig, n_rows: int, *,
+                   table: bool) -> tuple:
+    """``(feats, neighbors, weights)`` as ``forward``'s ``fused_ideal_layer``
+    launches read them: the ``[..., Nd, S]`` sample chunked, with the run
+    tables of every layer's block size (``prepare_sample``), and with
+    ``table`` the ``[..., N, F]`` features as layer 1's padded row view
+    (``prepare_table``); ``feats`` as given otherwise. ``n_rows``: the
+    rows of the table each layer gathers from. For the ``fused`` backend
+    with ideal numerics; the sizes come from ``ideal_launch``, the
+    launch's own rule, so ``layer_step`` takes the results as they are."""
+    launches = [ideal_launch(n_rows, neighbors.shape, a, b, cfg.tuned)
+                for a, b in zip(cfg.dims[:-1], cfg.dims[1:])]
+    neighbors, weights = prepare_sample(neighbors, weights, n_rows,
+                                        sorted({blk for _, blk in launches}))
+    if table:
+        feats = prepare_table(feats, launches[0][0])
+    return feats, neighbors, weights
 
 
 @partial(jax.jit, static_argnames="cfg")
@@ -115,8 +144,8 @@ def forward(params: list, x: jax.Array, neighbors: jax.Array,
 @partial(jax.jit, static_argnames="cfg")
 def centralized_forward(params: list, feats: jax.Array, neighbors: jax.Array,
                         weights: jax.Array, cfg: GNNConfig) -> jax.Array:
-    """``forward`` over a centralized plan's ``[1, N, ...]`` tables;
-    returns ``[1, N, out_dim]``."""
+    """``forward`` over a centralized plan's ``[1, N, ...]`` tables, raw or
+    as ``fused_operands`` built them; returns ``[1, N, out_dim]``."""
     return forward(params, feats[0], neighbors[0], weights[0], cfg)[None]
 
 

@@ -656,7 +656,7 @@ class ExecutionPlan:
             feats = tuple(jnp.asarray(f) for f in self.feats)
             return fn, (feats, nbrs, wtss)
         spmd = self._spmd(mesh)
-        args = self.device_inputs(mesh if spmd else None)
+        args = self.forward_inputs(cfg, mesh if spmd else None)
         if self.setting == "centralized":
             from repro.core import gnn
             return gnn.centralized_forward, (*args, cfg)
@@ -702,6 +702,50 @@ class ExecutionPlan:
             sharding = NamedSharding(mesh, PartitionSpec("data"))
             put = lambda a: jax.device_put(a, sharding)  # noqa: E731
         return put(self.feats), put(self.neighbors), put(self.weights)
+
+    def forward_inputs(self, cfg, mesh=None):
+        """The dense plan's (feats, neighbors, weights) as its forward reads
+        them: ``device_inputs``, and where the forward launches
+        ``fused_ideal_layer`` (``_prepares``), its operands that no update
+        changes prepared on the device once, by the launches' own rule
+        (``gnn.fused_operands``): the sample tables chunked with their run
+        tables, and on a centralized plan the features as layer 1's padded
+        row view. Each forward built reads the plan's tables as they are
+        then; with a ``mesh``, the results are split like the inputs.
+        Span ``plan.prepare``: ``tables`` prepared and the ``bytes`` they
+        hold on the devices."""
+        import jax
+        from repro.core import gnn
+        cfg = self.gnn_config(cfg)
+        args = self.device_inputs(mesh)
+        table, sample = self._prepares(cfg)
+        if not sample:
+            return args
+        with tel.span("plan.prepare") as sp:
+            out = gnn.fused_operands(*args, cfg, self._table_rows,
+                                     table=table)
+            if mesh is not None:
+                out = jax.device_put(out, args[0].sharding)
+            built = jax.tree.leaves(out if table else out[1:])
+            sp.set(tables=len(built), bytes=sum(a.nbytes for a in built))
+        return out
+
+    def _prepares(self, cfg) -> tuple:
+        """``(table, sample)``: whether the forward's fused launches read a
+        prepared layer-1 feature table and prepared sample tables. Dense
+        plans on the ``fused`` backend with ideal numerics prepare their
+        sample; a centralized one its features too, which the other
+        placements rebuild every layer from the exchange."""
+        sample = (cfg.backend == "fused" and cfg.numerics.ideal
+                  and self.bucketed is None)
+        return sample and self.setting == "centralized", sample
+
+    @property
+    def _table_rows(self) -> int:
+        """Rows of the table a dense plan's layers gather from: the graph
+        on one device, else owned plus halo rows."""
+        return (self.graph.n_nodes if self.setting == "centralized"
+                else self.part.n_max + self.part.h_max)
 
     def scatter(self, out) -> np.ndarray:
         """Map per-cluster outputs [K, n_max, D] to global node order.
@@ -847,9 +891,7 @@ class ExecutionPlan:
                         zip(self.neighbors, b.n_caps, b.h_caps)
                         for nbr in nbrs]
         else:
-            n = (self.graph.n_nodes if self.setting == "centralized"
-                 else self.part.n_max + self.part.h_max)
-            launches = [(nbr, n) for nbr in self.neighbors]
+            launches = [(nbr, self._table_rows) for nbr in self.neighbors]
         dims = (cfg.in_dim, *cfg.hidden_dims, cfg.out_dim)
         rows = blocks = 0
         for f_in, f_out in zip(dims[:-1], dims[1:]):
@@ -857,6 +899,20 @@ class ExecutionPlan:
                 r, b = ideal_layer_dmas(nbr, n, f_in, f_out, tuned=cfg.tuned)
                 rows, blocks = rows + r, blocks + b
         return {"row_dmas": rows, "block_dmas": blocks}
+
+    def prepared_launches(self, cfg) -> dict:
+        """How many of one forward's ``fused_ideal_layer`` launches, summed
+        over layers and clusters, read an operand ``forward_inputs``
+        prepared: ``prepared_launches`` a prepared feature table (layer 1
+        of a centralized plan), ``prepared_samples`` prepared sample
+        tables. Empty where the forward prepares nothing."""
+        cfg = self.gnn_config(cfg)
+        table, sample = self._prepares(cfg)
+        if not sample:
+            return {}
+        k, layers = len(self.neighbors), len(cfg.dims) - 1
+        return {"prepared_launches": k * table,
+                "prepared_samples": k * layers}
 
     def measured_traffic(self, cfg=None, mode: str = "alltoall"):
         """Measured wire traffic of this plan's exchanges — the runtime

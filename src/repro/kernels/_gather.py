@@ -13,6 +13,11 @@ table, in one of two ways (DESIGN.md §5):
     when the destination rows are the table's own, ``block_runs`` finds
     the run and the kernel moves it with one DMA of ``block_rows`` rows
     instead of one a row; ``gather_dmas`` counts what a launch issues.
+    The launch reads its neighbour table as ``BlockNeighbors``: each
+    chunk's indices flat, with a run table per block size. A plan whose
+    sample does not change between calls builds it once
+    (``block_neighbors``) and passes it in place of the ``[Nd, S]``
+    table; given a raw table, the launch builds it itself.
 
 Two limits of the TPU compiler shape both:
 
@@ -37,6 +42,8 @@ kernel ran and how many grid steps it took.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -47,8 +54,9 @@ GATHER_BUFFER_BYTES = 8 << 20   # both VMEM buffers of a block gather
 
 
 def rows_view(a: jax.Array) -> jax.Array:
-    """``[N, F] -> [N, 1, F]``: one row per block under ``row_block``."""
-    return a.reshape(a.shape[0], 1, a.shape[1])
+    """``[..., N, F] -> [..., N, 1, F]``: one row per block under
+    ``row_block``."""
+    return a.reshape(*a.shape[:-1], 1, a.shape[-1])
 
 
 def row_block(width: int) -> tuple:
@@ -56,12 +64,19 @@ def row_block(width: int) -> tuple:
     return (pl.Squeezed(), 1, width)
 
 
+def chunk_padded(rows: int) -> int:
+    """Rows of a block gather's chunk of ``rows`` destination rows, weight-0
+    padding included: ``rows`` rounded up to 8. Every ``block_rows(rows,
+    ...)`` divides it, so one chunked table serves every block size."""
+    return -(-rows // 8) * 8
+
+
 def block_rows(rows: int, slots: int, width: int) -> int:
     """Destination rows a block gather handles per grid step: the largest
-    multiple of 8 that divides ``rows`` rounded up to 8, for which its two
+    multiple of 8 that divides ``chunk_padded(rows)``, for which its two
     float32 buffers of ``slots`` rows each, ``2 R slots width`` lane-padded
     entries, fit ``GATHER_BUFFER_BYTES``."""
-    rows8 = -(-rows // 8) * 8
+    rows8 = chunk_padded(rows)
     lanes = -(-width // 128) * 128
     r = min(max(GATHER_BUFFER_BYTES // (8 * slots * lanes) // 8 * 8, 8),
             rows8)
@@ -89,14 +104,16 @@ def chunk_rows(nd: int, slots: int) -> int:
 
 
 def chunk_tables(table, rows: int, padded: int):
-    """``[Nd, S] -> [C, padded, S]``: the table cut into chunks of ``rows``
-    destination rows, each chunk zero padded to ``padded`` rows, the short
-    last one too. Works on numpy and jnp tables alike."""
+    """``[..., Nd, S] -> [..., C, padded, S]``: the table cut into chunks of
+    ``rows`` destination rows, each chunk zero padded to ``padded`` rows,
+    the short last one too. Works on numpy and jnp tables alike."""
     xp = np if isinstance(table, np.ndarray) else jnp
-    nd, s = table.shape
+    *lead, nd, s = table.shape
     c = -(-nd // rows)
-    t = xp.pad(table, ((0, c * rows - nd), (0, 0))).reshape(c, rows, s)
-    return xp.pad(t, ((0, 0), (0, padded - rows), (0, 0)))
+    keep = [(0, 0)] * len(lead)
+    t = xp.pad(table, keep + [(0, c * rows - nd), (0, 0)])
+    return xp.pad(t.reshape(*lead, c, rows, s),
+                  keep + [(0, 0), (0, padded - rows), (0, 0)])
 
 
 def map_chunks(call, *tables):
@@ -133,6 +150,56 @@ def block_runs(nbr, block: int, n_rows: int):
                     axis=-1).astype(xp.int32)
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class BlockNeighbors:
+    """An ``[..., Nd, S]`` neighbour table in the form a block gather's
+    launch reads it (``block_neighbors``), passed where the raw table
+    would go.
+
+    ``nbr`` is ``[..., C, M * S]``: each chunk of ``chunk_rows(Nd, S)``
+    destination rows, padded to ``M = chunk_padded(...)`` rows, flat as
+    the launch prefetches it. ``runs`` maps each block size it was built
+    for to that block size's ``block_runs`` table, flat per chunk (``[...,
+    C, 2 M / block]``), over a table of ``n_rows`` rows. ``shape`` is the
+    raw table's, and ``t[c]`` indexes every array's leading axes, as it
+    would the raw table's: ``t[c]`` is cluster ``c``'s sample."""
+    nbr: jax.Array
+    runs: dict
+    nd: int = dataclasses.field(metadata=dict(static=True))
+    slots: int = dataclasses.field(metadata=dict(static=True))
+    n_rows: int = dataclasses.field(metadata=dict(static=True))
+
+    @property
+    def shape(self) -> tuple:
+        return (*self.nbr.shape[:-2], self.nd, self.slots)
+
+    def __getitem__(self, i) -> "BlockNeighbors":
+        return jax.tree.map(lambda a: a[i], self)
+
+    def launch(self, n_rows: int, block: int) -> tuple:
+        """``(nbr, runs)`` of a launch over an ``n_rows``-row table that
+        gathers ``block`` rows a grid step."""
+        if n_rows != self.n_rows or block not in self.runs:
+            raise ValueError(
+                f"neighbours prepared for a {self.n_rows}-row table and "
+                f"blocks {sorted(self.runs)}; the launch reads a "
+                f"{n_rows}-row table in blocks of {block}")
+        return self.nbr, self.runs[block]
+
+
+def block_neighbors(neighbors, n_rows: int, blocks) -> BlockNeighbors:
+    """The ``[..., Nd, S]`` ``neighbors`` of an ``n_rows``-row table as the
+    block gather reads them, with a run table for each of ``blocks``
+    (``BlockNeighbors``). Works on numpy and jnp tables alike."""
+    *lead, nd, s = neighbors.shape
+    rows = chunk_rows(nd, s)
+    t = chunk_tables(neighbors, rows, chunk_padded(rows))
+    flat = lambda a: a.reshape(*a.shape[:-2], -1)   # noqa: E731
+    return BlockNeighbors(flat(t), {b: flat(block_runs(t, b, n_rows))
+                                    for b in blocks}, nd, s, n_rows)
+
+
 def gather_dmas(neighbors: np.ndarray, n_rows: int, width: int) -> tuple:
     """``(row DMAs, block DMAs)`` that one ``fused_ideal_layer`` launch
     issues over the ``[Nd, S]`` sample of an ``n_rows`` x ``width`` table:
@@ -142,8 +209,7 @@ def gather_dmas(neighbors: np.ndarray, n_rows: int, width: int) -> tuple:
     nd, s = neighbors.shape
     rows = chunk_rows(nd, s)
     block = block_rows(rows, s, width)
-    padded = -(-rows // block) * block
-    nbr = chunk_tables(np.asarray(neighbors), rows, padded)
+    nbr = chunk_tables(np.asarray(neighbors), rows, chunk_padded(rows))
     runs = int((block_runs(nbr, block, n_rows)[..., 0] >= 0).sum())
     return nbr.size - runs * block, runs
 

@@ -26,10 +26,10 @@ stays in HBM; the rows of block ``j + 1`` are copied by hand, one DMA per
 A slot whose rows over block ``j`` are consecutive table rows, ``nbr[jR +
 r, s] == nbr[jR, s] + r`` (a self loop where the destination rows lead the
 table), is a run: one DMA of ``[R, 1, F]`` moves it, and only the other
-slots take row DMAs. The launch finds the runs once (``_gather.block_runs``,
-the first run slot of each block) and prefetches them beside ``nbr``; the
-kernel picks one of ``S + 1`` static issue loops per block, so the loop
-over rows tests nothing per DMA. The bytes land where the row DMAs would
+slots take row DMAs. The runs are found once (``_gather.block_runs``, the
+first run slot of each block), by the launch or beforehand, and prefetched
+beside ``nbr``; the kernel picks one of ``S + 1`` static issue loops per
+block, so the loop over rows tests nothing per DMA. The bytes land where the row DMAs would
 put them, so the one wait per buffer and every output bit are unchanged.
 
 The bit-accurate path's two kernels gather one row-slot per grid step:
@@ -55,7 +55,10 @@ identical to the standalone ``crossbar_mvm`` kernel.
 
 Rows move through the ``[N, 1, F]`` row view, and destination rows are
 chunked across calls so the flat prefetched tables fit SMEM at any node
-count (``kernels._gather``, DESIGN.md §5).
+count (``kernels._gather``, DESIGN.md §5). ``fused_ideal_layer`` takes the
+row view, the chunked tables and the run tables as they are where the
+caller built them once (a plan whose features and sample do not change
+between calls), and builds them itself from raw tables.
 """
 from __future__ import annotations
 
@@ -66,7 +69,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._gather import (block_rows, block_runs, chunk_rows,
+from repro.kernels._gather import (BlockNeighbors, block_neighbors,
+                                  block_rows, chunk_padded, chunk_rows,
                                   chunk_tables, gather_metadata, map_chunks,
                                   map_row_chunks, row_block, rows_view)
 from repro.kernels._interpret import resolve_interpret
@@ -216,26 +220,33 @@ def fused_ideal_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
                       interpret: bool | None = None) -> jax.Array:
     """act((A_hat @ X) @ W + b) in one kernel, ideal float numerics.
 
-    x: [N, F]; neighbors/weights: [Nd, S]; w: [F, H]; b: [H].
-    Returns [Nd, H] float32. Z never touches HBM. Each chunk of
-    destination rows runs as ``block_rows`` blocks, zero-weight rows
-    padding the last; a block's run slot, if it has one, is fetched in one
-    DMA (``_gather.block_runs``).
+    x: [N, F], or its ``[N, 1, F]`` row view (``rows_view``); neighbors/
+    weights: [Nd, S], or the sample as the launch reads it, built once
+    beforehand: ``BlockNeighbors`` with a run table for this launch's
+    block size, and the weights chunked as ``chunk_tables`` cuts them
+    (``[C, M, L]`` float32, lanes from ``S`` on zero: the weight block is
+    ``(block, L)``); w: [F, H]; b: [H]. Each is taken as given,
+    and built here where it is raw. Returns [Nd, H] float32. Z never
+    touches HBM. Each chunk of destination rows runs as ``block_rows``
+    blocks, zero-weight rows padding the last; a block's run slot, if it
+    has one, is fetched in one DMA (``_gather.block_runs``).
     """
     interpret = resolve_interpret(interpret)
-    n, f = x.shape
+    x_rows = x if x.ndim == 3 else rows_view(x)
+    n, _, f = x_rows.shape
     nd, n_s = neighbors.shape
     f2, h = w.shape
     assert f == f2, (x.shape, w.shape)
-    x_rows = rows_view(x)
     w = w.astype(jnp.float32)
     b = b.astype(jnp.float32).reshape(1, h)
     rows = chunk_rows(nd, n_s)
+    padded = chunk_padded(rows)             # weight-0 rows, dropped below
     block = block_rows(rows, n_s, f)
-    padded = -(-rows // block) * block      # weight-0 rows, dropped below
-    nbr, wts = (chunk_tables(t, rows, padded)
-                for t in (neighbors, weights.astype(jnp.float32)))
-    runs = block_runs(nbr, block, n)        # [chunks, blocks, 2], one pass
+    if not isinstance(neighbors, BlockNeighbors):
+        neighbors = block_neighbors(neighbors, n, (block,))
+    nbr, runs = neighbors.launch(n, block)  # flat per chunk
+    wts = (weights if weights.ndim == 3 else
+           chunk_tables(weights.astype(jnp.float32), rows, padded))
 
     def call(nbr, wts, runs):
         grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -243,7 +254,7 @@ def fused_ideal_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
             grid=(padded // block,),
             in_specs=[
                 pl.BlockSpec(memory_space=pl.ANY),            # X in HBM
-                pl.BlockSpec((block, n_s), lambda j, *_: (j, 0)),
+                pl.BlockSpec((block, wts.shape[-1]), lambda j, *_: (j, 0)),
                 pl.BlockSpec((f, h), lambda j, *_: (0, 0)),   # W resident
                 pl.BlockSpec((1, h), lambda j, *_: (0, 0)),   # bias
             ],
@@ -263,7 +274,7 @@ def fused_ideal_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
             interpret=interpret, name="fused_ideal_layer",
             metadata=gather_metadata("fused_ideal_layer", padded, n_s, f, h,
                                      block_rows=block),
-        )(nbr.reshape(-1), runs.reshape(-1), x_rows, wts, w, b)
+        )(nbr, runs, x_rows, wts, w, b)
         # Without the barrier XLA fuses the chunk's write into the stacked
         # output with the launch, and a profile then shows a fusion that
         # carries neither the custom call nor its kernel_metadata.

@@ -15,6 +15,15 @@ over Z), and dispatches to the right kernel:
 The package ends at one layer: ``core.gnn.layer_step`` calls
 ``fused_gnn_layer`` for the ``fused`` backend, and the layer loops of every
 placement live above it (``core.gnn.forward``, ``distributed.halo``).
+
+Operands that do not change between calls can be built once in the form
+the ideal launch reads them: ``prepare_table`` pads a feature table to the
+launch's lanes and views it by rows (``[N, 1, k_pad]``), and
+``prepare_sample`` chunks the sample tables and finds their runs for the
+given block sizes; ``ideal_launch`` gives both sizes by the launch's own
+rule. ``fused_gnn_layer`` tells them from raw tables by their form (a
+three-dimensional table, ``BlockNeighbors``, three-dimensional weights) and
+reads them as they are; the bit-accurate path takes raw tables only.
 """
 from __future__ import annotations
 
@@ -24,7 +33,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels._gather import gather_dmas
+from repro.kernels._gather import (BlockNeighbors, block_neighbors,
+                                  block_rows, chunk_padded, chunk_rows,
+                                  chunk_tables, gather_dmas, rows_view)
 from repro.kernels.crossbar_mvm.ref import (CrossbarNumerics,
                                             apply_conductance_noise,
                                             quantize_weights)
@@ -45,19 +56,32 @@ def _pad_rows(a: jax.Array, to: int) -> jax.Array:
     return jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1)) if pad else a
 
 
-def _resolve_bf(x_shape, nbr_shape, f_out, cfg, bf, tuned):
+def _resolve_bf(n, f_in, nbr_shape, f_out, cfg, bf, tuned):
     """Lane block for this launch: explicit ``bf`` wins, else the tuned
     bundle, else the process tuning registry, else the 128 default.
     Resolution is eager (outside the jitted impl); callers inside an outer
     jit thread ``tuned`` so the decision is part of the jit key."""
     if bf is not None:
         return bf
-    geom = FusedGeometry(nd=nbr_shape[0], n=x_shape[0], f_in=x_shape[1],
-                         f_out=f_out, sample=nbr_shape[1], ideal=cfg.ideal,
+    geom = FusedGeometry(nd=nbr_shape[-2], n=n, f_in=f_in, f_out=f_out,
+                         sample=nbr_shape[-1], ideal=cfg.ideal,
                          rows_per_xbar=cfg.rows_per_xbar)
     c = ((tuned.lookup(geom.key()) if tuned is not None else None)
          or _tuning_registry.lookup(geom.key()))
     return c.bf if c else 128
+
+
+def ideal_launch(n: int, nbr_shape: tuple, f_in: int, f_out: int,
+                 tuned=None) -> tuple:
+    """``(k_pad, block)`` of the ``fused_ideal_layer`` launch that
+    ``fused_gnn_layer`` makes with ideal numerics over an ``[n, f_in]``
+    table and an ``[..., Nd, S]`` sample: the lanes it pads the table to,
+    and the destination rows a grid step gathers."""
+    cfg = CrossbarNumerics(ideal=True)
+    bf = _resolve_bf(n, f_in, nbr_shape, f_out, cfg, None, tuned)
+    k_pad = padded_grid(n, f_in, f_out, bf, bm=1, bn=bf).k_pad
+    nd, s = nbr_shape[-2:]
+    return k_pad, block_rows(chunk_rows(nd, s), s, k_pad)
 
 
 def ideal_layer_dmas(neighbors: np.ndarray, n: int, f_in: int, f_out: int,
@@ -65,11 +89,32 @@ def ideal_layer_dmas(neighbors: np.ndarray, n: int, f_in: int, f_out: int,
     """``(row DMAs, block DMAs)`` of the ``fused_ideal_layer`` launch that
     ``fused_gnn_layer`` makes with ideal numerics over an ``[n, f_in]``
     table and the ``[Nd, S]`` sample ``neighbors``: the lane padding the
-    launch gets, then ``_gather.gather_dmas``."""
-    cfg = CrossbarNumerics(ideal=True)
-    bf = _resolve_bf((n, f_in), neighbors.shape, f_out, cfg, None, tuned)
-    k_pad = padded_grid(n, f_in, f_out, bf, bm=1, bn=bf).k_pad
+    launch gets (``ideal_launch``), then ``_gather.gather_dmas``."""
+    k_pad, _ = ideal_launch(n, neighbors.shape, f_in, f_out, tuned)
     return gather_dmas(neighbors, n, k_pad)
+
+
+def prepare_table(x: jax.Array, k_pad: int) -> jax.Array:
+    """``[..., N, F] -> [..., N, 1, k_pad]``: a feature table zero-padded to
+    a launch's ``k_pad`` lanes (``ideal_launch``) and viewed by rows, as
+    the ideal launch reads it. ``fused_gnn_layer`` takes it in place of the
+    ``[N, F]`` table, bit for bit alike."""
+    return rows_view(_pad_cols(x, k_pad))
+
+
+def prepare_sample(neighbors, weights, n_rows: int, blocks) -> tuple:
+    """``(BlockNeighbors, [..., C, M, L] weights)``: the ``[..., Nd, S]``
+    sample of an ``n_rows``-row table as the ideal launches read it, with
+    a run table for each block size of ``blocks`` (``ideal_launch``), and
+    the weights chunked and zero-padded to ``L``, ``S`` rounded up to 128
+    lanes: the layout the launch's weight block has in memory, which a
+    device keeps for this shape as it is. ``fused_gnn_layer`` takes the
+    pair in place of the raw tables, bit for bit alike."""
+    nd, s = neighbors.shape[-2:]
+    rows = chunk_rows(nd, s)
+    wts = chunk_tables(weights.astype(jnp.float32), rows, chunk_padded(rows))
+    return (block_neighbors(neighbors, n_rows, blocks),
+            _pad_cols(wts, -(-s // 128) * 128))
 
 
 def fused_gnn_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
@@ -81,6 +126,9 @@ def fused_gnn_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
     """act((A_hat @ X) @ W + b) with Z resident in VMEM throughout.
 
     x: [N, F]; neighbors: [Nd, S] int32; weights: [Nd, S]; w: [F, H]; b: [H].
+    With ideal numerics ``x`` may instead be ``prepare_table``'s row view
+    and ``neighbors``/``weights`` ``prepare_sample``'s pair, each built for
+    this launch (``ideal_launch``); they are read as they are.
     Matches ``ref.fused_layer_ref`` (the composed csr_aggregate +
     crossbar_mvm path) for both ideal and bit-accurate ``cfg``. ``bf``
     left at ``None`` resolves through the tuned bundle / registry
@@ -89,7 +137,8 @@ def fused_gnn_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
     conductance-code perturbation on the programmed weights
     (``devices.variation``) — ignored on the ideal path.
     """
-    bf = _resolve_bf(x.shape, neighbors.shape, w.shape[1], cfg, bf, tuned)
+    bf = _resolve_bf(x.shape[0], w.shape[0], neighbors.shape, w.shape[1],
+                     cfg, bf, tuned)
     return _fused_gnn_layer(x, neighbors, weights, w, b, cfg, relu=relu,
                             bf=bf, interpret=interpret, w_noise=w_noise)
 
@@ -102,21 +151,27 @@ def _fused_gnn_layer(x: jax.Array, neighbors: jax.Array, weights: jax.Array,
                      *, relu: bool, bf: int,
                      interpret: bool | None,
                      w_noise: jax.Array | None = None) -> jax.Array:
-    n, f = x.shape
-    f2, h = w.shape
-    assert f == f2, (x.shape, w.shape)
+    n, (f, h) = x.shape[0], w.shape
     # the mapper emits the padded tile grid for either numerics path: K
     # tiled into physical rows_per_xbar crossbars (bit-accurate) or into
     # bf-lane MXU blocks (ideal), H lane-aligned to bf — arbitrary F/H map.
     grid = padded_grid(n, f, h, bf if cfg.ideal else cfg.rows_per_xbar,
                        bm=1, bn=bf)
+    view = x.ndim == 3                  # prepare_table's row view
+    want = grid.k_pad if view else f
+    if x.shape[-1] != want:
+        raise ValueError(f"table {x.shape} against weights {w.shape}: the "
+                         f"launch reads {want} lanes")
     if cfg.ideal:
-        xp = _pad_cols(x, grid.k_pad)
+        xp = x if view else _pad_cols(x, grid.k_pad)
         wp = _pad_cols(_pad_rows(w, grid.k_pad), grid.n_pad)
         bp = _pad_cols(b[None], grid.n_pad)[0]
         out = fused_ideal_layer(xp, neighbors, weights, wp, bp,
                                 relu=relu, interpret=interpret)
         return out[:, :h]
+    if view or isinstance(neighbors, BlockNeighbors) or weights.ndim == 3:
+        raise ValueError("the bit-accurate layer reads raw [N, F] and "
+                         "[Nd, S] tables, not prepared ones")
     xp = _pad_cols(x, grid.k_pad)
     zmax = fused_zmax(xp, neighbors, weights, interpret=interpret)
     # global DAC scales of max(Z,0) / max(-Z,0) — identical to

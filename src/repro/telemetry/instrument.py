@@ -26,7 +26,10 @@ boundary instead:
      the DMAs the ``fused_ideal_layer`` launches issue in one forward,
      summed over layers and chips: ``row_dmas``, one a (row, slot), and
      ``block_dmas``, one for each block whose slot is a run of
-     consecutive table rows (``ExecutionPlan.gather_dmas``).
+     consecutive table rows (``ExecutionPlan.gather_dmas``), and how
+     many of them read operands the plan prepared once:
+     ``prepared_launches`` a prepared feature table, ``prepared_samples``
+     prepared sample tables (``ExecutionPlan.prepared_launches``).
 
 The traffic report and the DMA counts are computed lazily on the first
 *traced* call and cached — with telemetry disabled the wrapper is a flag check plus the
@@ -61,7 +64,8 @@ def instrument_forward(plan, cfg, mode: str, fwd: Callable,
                              send_rows=rep.send_rows,
                              launched_bytes=rep.launched_bytes())
                         if spmd else {})
-            launched.update(plan.gather_dmas(cfg))
+            launched.update(plan.gather_dmas(cfg),
+                            **plan.prepared_launches(cfg))
             billing = state["billing"] = (tier0, per_layer,
                                           tier0 + sum(per_layer), launched)
         tier0, per_layer, total, launched = billing

@@ -4,6 +4,7 @@ params/plan version moved, not only when embeddings were never computed
 on ``embeddings is None``)."""
 import numpy as np
 import jax
+import pytest
 
 from repro.core import gnn
 from repro.core.graph import random_graph
@@ -95,18 +96,26 @@ def test_update_plan_to_different_node_count_swaps_staleness_domain():
 
 def test_lower_forward_takes_tables_as_arguments():
     """``lower_forward`` lowers the program ``make_forward`` runs, with the
-    plan's tables as arguments (the chip check reads tpu_custom_call from
-    it) — and that program computes what the server serves."""
+    plan's tables as arguments of its ``main`` (the chip check reads
+    tpu_custom_call from it) — and that program computes what the server
+    serves. A centralized fused plan's ``main`` takes the features as the
+    first launch reads them: zero-padded from 24 to 128 lanes and viewed by
+    rows, ``[1, N, 1, 128]``; the other placements rebuild layer 1's table
+    from the exchange and take the features as they are."""
     for setting in ("centralized", "decentralized", "semi"):
         srv, cfg, g = _server(setting=setting, backend="fused",
                               n_clusters=None if setting == "centralized"
                               else 2)
         srv.refresh()
         lowered = srv.plan.lower_forward(srv.params, srv.cfg)
-        feats = srv.plan.feats
-        shape = "x".join(str(d) for d in feats.shape)
-        assert f"tensor<{shape}xf32>" in lowered.as_text()
-        out = lowered.compile()(srv.params, *srv.plan.device_inputs()[:3])
+        feats = ((1, g.n_nodes, 1, 128) if setting == "centralized"
+                 else srv.plan.feats.shape)
+        shape = "x".join(str(d) for d in feats)
+        main = next(line for line in lowered.as_text().splitlines()
+                    if "func.func public @main(" in line)
+        assert f"tensor<{shape}xf32>" in main
+        out = lowered.compile()(srv.params,
+                                *srv.plan.forward_inputs(srv.cfg))
         np.testing.assert_array_equal(srv.plan.scatter(out), srv.embeddings)
 
 
@@ -136,3 +145,80 @@ def test_scatter_puts_each_cluster_row_at_its_node():
             want[part.local_nodes[c][m]] = out[c][m]
         assert not np.isnan(want).any(), "every node is some cluster's row"
         np.testing.assert_array_equal(srv.plan.scatter(out), want)
+
+
+def _raw_forward(srv, feats=None):
+    """``gnn.forward`` of ``srv``'s config over the plan's raw tables."""
+    import jax.numpy as jnp
+    plan = srv.plan
+    feats = plan.feats[0] if feats is None else feats
+    return np.asarray(gnn.forward(srv.params, jnp.asarray(feats),
+                                  jnp.asarray(plan.neighbors[0]),
+                                  jnp.asarray(plan.weights[0]), srv.cfg))
+
+
+def test_centralized_fused_forward_matches_gnn_forward():
+    """The centralized fused plan's forward reads its prepared feature and
+    sample tables, and returns ``gnn.forward`` over the raw tables bit for
+    bit."""
+    srv, _, _ = _server(backend="fused")
+    feats, nbr, _ = srv.plan.forward_inputs(srv.cfg)
+    assert feats.shape == (1, 40, 1, 128) and nbr.shape == (1, 40, 4)
+    srv.refresh()
+    np.testing.assert_array_equal(srv.embeddings, _raw_forward(srv))
+
+
+@pytest.mark.parametrize("backend,ideal", [("fused", False),
+                                           ("jnp", True), ("pallas", True)])
+def test_raw_table_callers_are_unchanged(backend, ideal):
+    """Off the ideal fused launch (bit-accurate numerics, the jnp and
+    pallas backends) the forward takes the plan's tables as they are, and
+    matches ``gnn.forward`` over them."""
+    from repro.kernels.crossbar_mvm import CrossbarNumerics
+    numerics = (CrossbarNumerics(ideal=True) if ideal else CrossbarNumerics(
+        in_bits=8, w_bits=8, adc_bits=12, rows_per_xbar=64))
+    g = random_graph(40, 200, 24, seed=0).gcn_normalize()
+    plan = plan_execution(g, "centralized", sample=4, backend=backend)
+    cfg = gnn.GNNConfig(in_dim=24, hidden_dims=(16,), out_dim=8, sample=4,
+                        numerics=numerics)
+    srv = GNNServer(plan, cfg)
+    ins = plan.forward_inputs(srv.cfg)
+    assert [a.shape for a in ins] == [
+        a.shape for a in (plan.feats, plan.neighbors, plan.weights)]
+    srv.refresh()
+    np.testing.assert_array_equal(srv.embeddings, _raw_forward(srv))
+
+
+@pytest.mark.parametrize("how", ["stream", "update_plan"])
+def test_forward_built_after_a_feature_update_reads_the_new_rows(how):
+    """A forward is built from the plan's features as they are then: after
+    a streaming commit (``_sync_plan_feats``) or ``update_plan``, the next
+    forward built reads the new rows, bit for bit as ``gnn.forward`` over
+    them; a forward built before keeps the features it prepared."""
+    from repro.streaming import StreamingGNNServer
+    g = random_graph(40, 200, 24, seed=0).gcn_normalize()
+    plan = plan_execution(g, "centralized", sample=4, backend="fused")
+    cfg = gnn.GNNConfig(in_dim=24, hidden_dims=(16,), out_dim=8, sample=4)
+    if how == "update_plan":
+        srv = GNNServer(plan, cfg)
+        srv.refresh()
+        old = srv.embeddings
+        g2 = random_graph(40, 200, 24, seed=9).gcn_normalize()
+        srv.update_plan(plan_execution(g2, "centralized", sample=4,
+                                       backend="fused"), cfg)
+        srv.refresh()
+        np.testing.assert_array_equal(srv.embeddings, _raw_forward(srv))
+        assert not np.array_equal(srv.embeddings, old)
+        return
+    srv = StreamingGNNServer(plan, cfg, policy="eager")
+    before = plan.make_forward(srv.cfg)
+    old = np.asarray(before(srv.params))[0]
+    nodes = np.arange(0, 40, 3)
+    rows = np.random.default_rng(3).normal(size=(len(nodes), 24))
+    srv.ingest(nodes=nodes, rows=rows.astype(np.float32))
+    new_feats = srv.engine.graph.features
+    assert np.array_equal(plan.feats[0], new_feats)
+    after = np.asarray(plan.make_forward(srv.cfg)(srv.params))[0]
+    np.testing.assert_array_equal(after, _raw_forward(srv, new_feats))
+    assert not np.array_equal(after, old)
+    np.testing.assert_array_equal(np.asarray(before(srv.params))[0], old)

@@ -18,7 +18,8 @@ from repro.kernels._gather import (block_rows, block_runs, chunk_rows,
                                   chunk_tables, gather_dmas)
 from repro.kernels.crossbar_mvm import CrossbarNumerics
 from repro.kernels.fused_layer import (fused_gnn_layer, fused_ideal_layer,
-                                       fused_layer_ref)
+                                       fused_layer_ref, ideal_launch,
+                                       prepare_sample, prepare_table)
 
 QUANT = CrossbarNumerics(in_bits=8, w_bits=8, adc_bits=12, rows_per_xbar=64)
 
@@ -222,6 +223,52 @@ def test_block_gather_z_bit_exact(nd, s, tables):
     want = _slot_order_layer(x, nbr, wts, eye)
     got = fused_ideal_layer(x, nbr, wts, eye, jnp.zeros((f,)))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("prepared", ["table", "sample", "both"])
+@pytest.mark.parametrize("n,f,h,nd,s,tables", [
+    (300, 496, 64, 300, 8, "self"),      # F 496 -> 512 lanes; runs, and
+    #                                      a padded last block without one
+    (300, 64, 16, 300, 8, "self-ragged"),    # F 64 -> 128; runs in some blocks
+    (300, 128, 32, 4500, 8, "self"),     # Nd over one chunk, the last short
+    (60, 200, 24, 70, 8, "padded"),      # no run slot anywhere
+])
+def test_prepared_operands_match_raw(n, f, h, nd, s, tables, prepared):
+    """``fused_gnn_layer`` reads ``prepare_table``'s row view and
+    ``prepare_sample``'s tables, each built by ``ideal_launch``'s sizes,
+    as they are, and returns the raw path's output bit for bit, with and
+    without an activation."""
+    x, nbr, wts, w, b = _block_case(n, f, h, nd, s, n + nd, tables)
+    k_pad, block = ideal_launch(x.shape[0], nbr.shape, f, h)
+    xp, nbrp, wtsp = x, nbr, wts
+    if prepared in ("table", "both"):
+        xp = prepare_table(x, k_pad)
+        assert xp.shape == (x.shape[0], 1, k_pad)
+    if prepared in ("sample", "both"):
+        nbrp, wtsp = prepare_sample(nbr, wts, x.shape[0], (block,))
+        assert nbrp.shape == nbr.shape
+    for relu in (False, True):
+        want = fused_gnn_layer(x, nbr, wts, w, b, relu=relu)
+        got = fused_gnn_layer(xp, nbrp, wtsp, w, b, relu=relu)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_prepared_operands_refuse_what_they_do_not_fit():
+    """A sample prepared for another table or block size, a row view of
+    other lanes, and prepared operands on the bit-accurate path raise
+    instead of gathering the wrong rows."""
+    x, nbr, wts, w, b = _block_case(300, 496, 64, 300, 8, 0, "self")
+    k_pad, block = ideal_launch(300, nbr.shape, 496, 64)
+    nbrp, wtsp = prepare_sample(nbr, wts, 300, (block,))
+    with pytest.raises(ValueError, match="prepared for a 300-row table"):
+        fused_gnn_layer(x[:299], nbrp, wtsp, w, b)
+    with pytest.raises(ValueError, match="blocks of"):
+        fused_gnn_layer(x[:, :64], nbrp, wtsp, w[:64], b)
+    with pytest.raises(ValueError, match="lanes"):
+        fused_gnn_layer(prepare_table(x, 1024), nbr, wts, w, b)
+    with pytest.raises(ValueError, match="bit-accurate"):
+        fused_gnn_layer(prepare_table(x, k_pad), nbr, wts, w, b, QUANT,
+                        bf=128)
 
 
 def test_signed_activations_quantized():

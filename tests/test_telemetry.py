@@ -302,8 +302,57 @@ def test_gather_dmas_absent_off_the_fused_kernel(monkeypatch):
         plan.backend = backend
         (attrs,) = _forward_attrs(
             plan, dataclasses.replace(cfg, numerics=numerics))
-        assert not {"row_dmas", "block_dmas"} & set(attrs)
+        assert not {"row_dmas", "block_dmas", "prepared_launches",
+                    "prepared_samples"} & set(attrs)
+        assert not [r for r in tel.get_tracer().roots
+                    if r.name == "plan.prepare"]
     assert len(calls) == 2
+
+
+@pytest.mark.parametrize("setting,launches,samples", [
+    ("centralized", 1, 2), ("decentralized", 0, 4)])
+def test_forward_span_states_prepared_launches(setting, launches, samples):
+    """``plan.forward`` states how many fused launches of one forward read
+    operands the plan prepared, summed over layers and clusters: layer 1's
+    feature table on one device only (elsewhere the exchange rebuilds it
+    every layer), the sample tables in every launch."""
+    from repro.core import gnn
+    cfg = gnn.GNNConfig(in_dim=8, hidden_dims=(8,), out_dim=4, sample=4)
+    tel.enable()
+    (attrs,) = _forward_attrs(_fused_plan(setting), cfg)
+    assert attrs["prepared_launches"] == launches
+    assert attrs["prepared_samples"] == samples
+
+
+@pytest.mark.parametrize("setting", ["centralized", "decentralized"])
+def test_plan_prepare_span_once_per_forward_built(setting):
+    """Every forward built prepares the plan's operands once, in one
+    ``plan.prepare`` span stating how many tables it built and the bytes
+    they hold; calling the forward prepares nothing more. 64 rows a
+    cluster, S 4, one block size (64 rows at 128 lanes): the sample,
+    its run table and the weights, and on one device the features too."""
+    import jax
+    from repro.core import gnn
+    plan = _fused_plan(setting)
+    cfg = gnn.GNNConfig(in_dim=8, hidden_dims=(8,), out_dim=4, sample=4)
+    params = gnn.init_params(jax.random.key(0), plan.gnn_config(cfg))
+    tel.enable()
+    for _ in range(2):
+        fwd = plan.make_forward(cfg)
+        for _ in range(2):
+            jax.block_until_ready(fwd(params))
+    spans = [r for r in tel.get_tracer().roots if r.name == "plan.prepare"]
+    assert len(spans) == 2
+    built = jax.tree.leaves(plan.forward_inputs(cfg)[
+        0 if setting == "centralized" else 1:])
+    for sp in spans:
+        assert sp.attrs["tables"] == len(built)
+        assert sp.attrs["bytes"] == sum(a.nbytes for a in built)
+    if setting == "centralized":       # [1, 64, 1, 128] f32 features,
+        #                                 [1, 1, 256] nbr, [1, 1, 2] runs,
+        #                                 [1, 1, 64, 128] lane-padded weights
+        assert spans[0].attrs == {"tables": 4, "bytes": 4 * (
+            64 * 128 + 256 + 2 + 64 * 128)}
 
 
 # ---- streaming server: observer isolation + counters --------------------

@@ -12,7 +12,9 @@ its ``kernel_metadata``, which a profile's trace event of it carries.
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time.
 """
+import functools
 import json
+import math
 import os
 import re
 
@@ -21,11 +23,12 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import gnn
 from repro.kernels.cam_match import search
 from repro.kernels.crossbar_mvm import CrossbarNumerics
 from repro.kernels.crossbar_mvm.ops import crossbar_matmul
 from repro.kernels.csr_aggregate import aggregate
-from repro.kernels.fused_layer import fused_gnn_layer
+from repro.kernels.fused_layer import fused_gnn_layer, fused_layer
 
 S = 8
 # (name, table rows, destination rows, feature width)
@@ -164,3 +167,56 @@ def test_launches_carry_their_name_and_metadata(one_chip, name):
                          text, re.DOTALL):
         found[m.group(1).rsplit(".", 1)[0]] = json.loads(m.group(2))
     assert found == dict(LAUNCHES[name])
+
+
+@pytest.fixture
+def kernels_compiled(monkeypatch):
+    """Forwards pick interpret mode for their kernels on a CPU host;
+    compile them."""
+    monkeypatch.setattr(fused_layer, "resolve_interpret", lambda i: False)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("prepared", [True, False], ids=["prepared", "raw"])
+def test_prepared_feature_table_is_read_in_place(one_chip, kernels_compiled,
+                                                 prepared):
+    """The centralized fused forward at collab's size (372,475 x 496,
+    GCN 496-64-16, S 8), compiled with the tables a plan prepares for it
+    (``gnn.fused_operands``): outside the kernels' loops no copy, pad,
+    reshape, transpose or fusion makes an array the size of the feature
+    table, at 496 or 512 lanes; the first launch's loop reads the
+    parameter through a bitcast. With the raw ``[1, N, 496]`` tables the
+    same scan finds the relayout each update then pays, so the scan does
+    see one."""
+    n, f = 372_475, 496
+    cfg = gnn.GNNConfig(in_dim=f, hidden_dims=(64,), out_dim=16, sample=S,
+                        backend="fused")
+    args = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in (
+        ((1, n, f), jnp.float32), ((1, n, S), jnp.int32),
+        ((1, n, S), jnp.float32))]
+    if prepared:
+        args = jax.eval_shape(functools.partial(
+            gnn.fused_operands, cfg=cfg, n_rows=n, table=True), *args)
+        assert args[0].shape == (1, n, 1, 512)
+    dims = cfg.dims
+    params = [{"w": jax.ShapeDtypeStruct((a, b), jnp.float32),
+               "b": jax.ShapeDtypeStruct((b,), jnp.float32)}
+              for a, b in zip(dims[:-1], dims[1:])]
+    args, params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (args, params))
+    text = gnn.centralized_forward.lower(params, *args, cfg).compile(
+        ).as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}\n")]
+    table = {n * f, n * 512}
+    moved = [op for shape, op in re.findall(
+        r"= f32\[([\d,]+)\]\S* ([\w-]+)\(", entry)
+        if math.prod(int(d) for d in shape.split(",")) in table
+        and op not in ("parameter", "bitcast", "get-tuple-element")]
+    if prepared:
+        assert moved == []
+    else:
+        assert moved
