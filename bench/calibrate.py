@@ -7,12 +7,13 @@
 For each of ``--seeds``, one run of the cell as ``bench/run.py`` makes it,
 with a short window: the program's numbers (the lower readings). For each
 of ``--control-seeds``, the same run with the control in the program's
-place (``faults.broken("control")``: the reference computed in bfloat16
-serves every update), judged by the harness's own comparison: it must come
-out not correct, and its numbers are the upper readings. Everything runs
-in one process, so the program compiles once. Prints one JSON line per
-run, then a summary line, and exits non-zero if a program run is not
-correct or a control run is; the benchmark's own runs never run this.
+place (``faults.broken("control", kind)``: the model kind's reference
+computed in bfloat16 serves every update), judged by the harness's own
+comparison: it must come out not correct, and its numbers are the upper
+readings. Everything runs in one process, so the program compiles once.
+Prints one JSON line per run, then a summary line, and exits non-zero if
+a program run is not correct or a control run is; the benchmark's own
+runs never run this.
 """
 import time
 
@@ -62,7 +63,8 @@ def main() -> int:
     for kind, seeds in (("program", args.seeds),
                         ("control", args.control_seeds)):
         for seed in seed_list(seeds):
-            with faults.broken("none" if kind == "program" else "control"):
+            fault = "none" if kind == "program" else "control"
+            with faults.broken(fault, cfg["model"]["kind"]):
                 res, _ = harness.run_cell(spec, cell, cfg, mix, seed,
                                           args.seconds, False, devices,
                                           time.monotonic())

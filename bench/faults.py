@@ -1,7 +1,7 @@
 """Faults planted under a whole run of the timed path, each of which must
 make ``correct`` come out false, and the control in the program's place.
 
-``broken(fault)`` patches the program while a run is made; the rehearsal
+``broken(fault, kind)`` patches the program while a run is made; the rehearsal
 tests plant each fault on the CPU, and ``bench/calibrate.py`` runs the
 control through the harness on the chip. The benchmark's own runs never
 plant one.
@@ -14,25 +14,28 @@ from unittest import mock
 import jax
 import numpy as np
 
-from bench import graphs, reference
+from bench import graphs, kinds
 
 FAULTS = ("none", "unchanged", "half", "exchange", "answer", "control")
 
 
 @contextlib.contextmanager
-def broken(fault: str):
-    """The timed path with ``fault`` planted in the program underneath:
+def broken(fault: str, kind: str):
+    """The timed path of a cell of model kind ``kind`` with ``fault``
+    planted in the program underneath:
 
     unchanged  after set-up, ``refresh`` leaves the served table as it was
     half       ``scatter`` leaves every other node's row out (zeros)
     exchange   the halo all-to-all delivers nothing
     answer     one lookup's answer is altered where it is produced
-    control    the reference in bfloat16 takes the program's place
+    control    the model kind's reference in bfloat16 takes the program's
+               place
     """
     from repro.core.partition import ExecutionPlan
     from repro.distributed import halo
     from repro.launch.gnn import GNNServer
     assert fault in FAULTS, fault
+    model = kinds.load("models", kind)
     refresh, scatter, query = (GNNServer.refresh, ExecutionPlan.scatter,
                                GNNServer.query)
     calls = {"query": 0}
@@ -62,8 +65,8 @@ def broken(fault: str):
                "deg": np.diff(g.indptr)}
         nbr, wts = graphs.sample_table(csr, g.edge_weight, g.self_loop,
                                        self.plan.sample)
-        self.embeddings = reference.forward(g.features, nbr, wts,
-                                            [self.params], "bfloat16")[0]
+        self.embeddings = model.forward(g.features, nbr, wts,
+                                        [self.params], "bfloat16")[0]
         self.refreshes += 1
         self._served_version = self.version
         return 0.0

@@ -1,4 +1,4 @@
-"""The benchmark's own graphs, features and weights, made from seeds.
+"""The benchmark's own graphs and features, made from seeds.
 
 The program's ``core/graph.random_graph`` / ``dataset_like`` statistics,
 with a floor under the in-degree, so that the data does not move when the
@@ -8,9 +8,10 @@ program changes:
   * every node has at least ``min_in_degree`` in-edges; the destinations
     of the remaining edges are drawn zipf(1.6) modulo ``nodes`` (the
     program's heavy tail); sources are uniform;
-  * features N(0, 1) float32;
-  * GCN normalisation ``A_hat = D^-1/2 (A + I) D^-1/2``: edge (i <- j)
-    weighs 1/sqrt((d_i + 1)(d_j + 1)), the self loop of i 1/(d_i + 1).
+  * features N(0, 1) float32.
+
+The weights the graph stores on its edges are the model kind's
+(``bench/models/<kind>.py``).
 
 The floor is the graph's, not the program's: collab's mean in-degree is
 66, and ``dataset_like``'s zipf profile alone leaves 85% of the nodes with
@@ -23,8 +24,7 @@ with P(r) proportional to the Hurwitz zeta N^-a zeta(a, r/N) (r = N for
 residue 0), so it is drawn in one multinomial call instead of E zipf draws
 and a sort. Sources are independent of destinations, so they are drawn
 straight in destination order. The structure comes from the
-configuration's ``structure_seed``; features and weights from the run's
-seed.
+configuration's ``structure_seed``; features from the run's seed.
 """
 from __future__ import annotations
 
@@ -72,15 +72,6 @@ def csr(nodes: int, edges: int, structure_seed: int,
     return {"indptr": indptr, "indices": indices, "deg": deg}
 
 
-def gcn_weights(g: dict) -> tuple:
-    """``(edge_weight [E] float32, self_loop [N] float32)``."""
-    d1 = g["deg"].astype(np.float64) + 1.0
-    inv = 1.0 / np.sqrt(d1)
-    dst_inv = np.repeat(inv, g["deg"])
-    w = (dst_inv * inv[g["indices"]]).astype(np.float32)
-    return w, (1.0 / d1).astype(np.float32)
-
-
 def sample_table(g: dict, edge_weight: np.ndarray, self_loop: np.ndarray,
                  sample: int) -> tuple:
     """The model's fixed neighbour sample: each row holds its first
@@ -125,23 +116,3 @@ def features(key, nodes: int, width: int) -> np.ndarray:
 @partial(jax.jit, static_argnums=1)
 def _normal(key, shape):
     return jax.random.normal(key, shape, jnp.float32)
-
-
-def make_params_fn(dims):
-    """``fn(key, i) -> [{"w", "b"}] per layer``: the weights of update ``i``,
-    drawn on the device in one jitted call. W is Glorot-normal as in the
-    program's ``gnn.init_params``; b is 0.1 N(0, 1), so that the bias is
-    exercised (the program starts it at 0)."""
-
-    def fn(key, i):
-        key = jax.random.fold_in(key, i)
-        params = []
-        for f_in, f_out in zip(dims[:-1], dims[1:]):
-            key, kw, kb = jax.random.split(key, 3)
-            w = jax.random.normal(kw, (f_in, f_out), jnp.float32)
-            w = w * jnp.sqrt(2.0 / (f_in + f_out))
-            b = 0.1 * jax.random.normal(kb, (f_out,), jnp.float32)
-            params.append({"w": w, "b": b})
-        return params
-
-    return jax.jit(fn)

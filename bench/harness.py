@@ -2,24 +2,30 @@
 
 What a run does, in order:
 
-1. Checks that JAX's first device is a TPU and that there are as many as
-   the cell asks for; otherwise it prints why and exits non-zero. It never
-   falls back to the CPU.
+1. Refuses what it has no module for (``bench/kinds.py``): a model kind,
+   a ``numerics`` the model's reference cannot judge, or an update kind;
+   it prints one line naming them and exits non-zero. Then checks that
+   JAX's first device is a TPU and that there are as many as the cell asks
+   for; otherwise it prints why and exits non-zero. It never falls back to
+   the CPU.
 2. Set-up (``setup_s``, from process start to the window's first instant):
-   the graph (``graphs.csr``, from the configuration's structure seed), the
-   features (from the seed, drawn on the device), the program's plan
-   (``plan_execution``), the server (``GNNServer``, on a mesh for a cell of
-   several chips), a first update, which compiles, and a warm one.
-3. The window, ``--seconds`` long: updates run back to back, each
-   ``update_params`` with fresh weights from the seed and ``refresh()``
-   (the plan's forward on the chip, then ``scatter`` to the host table);
-   between updates one thread answers, in due order, the lookups
-   (``query``) that came due, from the traffic mix's schedule. The window
-   closes at the first update boundary after ``--seconds``.
+   the graph (``graphs.csr``, from the configuration's structure seed, with
+   the model kind's weights), the features (from the seed, drawn on the
+   device), the program's plan (``plan_execution``), the server
+   (``GNNServer``, on a mesh for a cell of several chips, running the model
+   kind's config), a first update, which compiles, and a warm one.
+3. The window, ``--seconds`` long: updates run back to back, each the
+   mix's update kind (``bench/updates/<kind>.py``: for ``refresh``, fresh
+   weights from the seed, then the plan's forward on the chip and
+   ``scatter`` to the host table); between updates one thread answers, in
+   due order, the lookups (``query``) that came due, from the traffic
+   mix's schedule. The window closes at the first update boundary after
+   ``--seconds``.
 4. After it: the device's peak memory, then the program is freed and the
-   plain reference (``reference.py``) recomputes a sample of the window's
-   updates, drawn from the seed, to decide ``correct``; every lookup's rows
-   are compared with the table it was served from.
+   model kind's plain reference (``bench/models/<kind>.py``) recomputes a
+   sample of the window's updates, drawn from the seed, from what the
+   update kind says each was served, to decide ``correct``; every lookup's
+   rows are compared with the table it was served from.
 5. The metrics: with ``--trace 0`` the cell's end-to-end metrics, with
    ``--trace 1`` its per-layer ones from a profiler trace of the window.
    Each is read by ``bench/metrics/<name>.py``.
@@ -33,7 +39,7 @@ import argparse
 import contextlib
 import dataclasses
 import gc
-import importlib.util
+import itertools
 import json
 import os
 import shutil
@@ -43,7 +49,7 @@ import time
 
 import numpy as np
 
-from bench import graphs, loadgen, peaks, reference, trace, work
+from bench import graphs, kinds, loadgen, peaks, reference, trace, work
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -86,12 +92,21 @@ def metric_names(spec: dict, cell: str, traced: bool) -> list:
 
 
 def load_reader(name: str, root: str = HERE):
-    path = os.path.join(root, "metrics", f"{name}.py")
-    mod_spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(mod_spec)
-    mod_spec.loader.exec_module(mod)
-    return mod.read
+    return kinds.load("metrics", name, root).read
+
+
+def kinds_of(cfg: dict, mix: dict) -> tuple:
+    """``(model module, update module)`` of a cell (``bench/kinds.py``), or
+    ``kinds.Refused``: a kind with no module, or a ``numerics`` the model's
+    reference cannot judge. Nothing runs one kind in another's place."""
+    kind = cfg["model"]["kind"]
+    model = kinds.load("models", kind)
+    if cfg["numerics"] not in model.NUMERICS:
+        raise kinds.Refused(
+            f"numerics {cfg['numerics']!r} cannot be judged by the reference"
+            f" of model kind {kind!r}; it judges: "
+            f"{', '.join(model.NUMERICS)}")
+    return model, kinds.load("updates", mix["update"]["kind"])
 
 
 def check_devices(chips: int) -> list:
@@ -131,6 +146,9 @@ class Records:
     work: list                    # per device, per layer {"flops", "bytes"}
     peaks: dict
     trace: dict | None = None     # trace.reduce() of the window
+    widths: tuple = ()            # the model's layer widths, input to output
+    sample: int = 0               # slots of the neighbour sample
+    rows: list = dataclasses.field(default_factory=list)  # [(owned, halo)]
 
 
 class _Compiles:
@@ -164,25 +182,23 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, seed: int,
              t_start: float) -> tuple:
     """One run; returns ``(result dict, check lines)``."""
     import jax
-    from repro.core import gnn
     from repro.core.graph import Graph
     from repro.core.partition import plan_execution
-    from repro.kernels.crossbar_mvm import CrossbarNumerics
     from repro.launch.gnn import GNNServer
     from repro.launch.mesh import make_mesh
 
+    model, update = kinds_of(cfg, mix)
     clock = time.perf_counter
     setup = {"start_and_devices_s": time.monotonic() - t_start}
     s_feat, s_wts, s_traffic, s_check = graphs.seeds(seed, 4)
     gcfg, mcfg = cfg["graph"], cfg["model"]
-    dims = (mcfg["in_dim"], *mcfg["hidden_dims"], mcfg["out_dim"])
     n_nodes, sample = gcfg["nodes"], mcfg["sample"]
     compiles = _compiles()
 
     t = clock()
     g = graphs.csr(n_nodes, gcfg["edges"], gcfg["structure_seed"],
                    gcfg["min_in_degree"])
-    edge_w, self_w = graphs.gcn_weights(g)
+    edge_w, self_w = model.graph_weights(g)
     setup["graph_s"] = clock() - t
     t = clock()
     x = graphs.features(graphs.jax_key(s_feat), n_nodes, gcfg["features"])
@@ -199,19 +215,15 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, seed: int,
 
     t = clock()
     mesh = (make_mesh((k,), ("data",)) if cell["chips"] > 1 else None)
-    model = gnn.GNNConfig(in_dim=dims[0], hidden_dims=tuple(dims[1:-1]),
-                          out_dim=dims[-1], sample=sample,
-                          numerics=CrossbarNumerics(
-                              ideal=cfg["numerics"] == "ideal"))
-    params_fn = graphs.make_params_fn(dims)
+    params_fn = model.params_fn(mcfg)
     wkey = graphs.jax_key(s_wts)
-    srv = GNNServer(plan, model, params=params_fn(wkey, np.int32(0)),
+    srv = GNNServer(plan, model.program_config(mcfg, cfg["numerics"]),
+                    params=params_fn(wkey, np.int32(0)),
                     mesh=mesh, mode=cfg["exchange"] or "alltoall")
     srv.refresh()
     setup["compile_and_first_update_s"] = clock() - t
     t = clock()
-    srv.update_params(params_fn(wkey, np.int32(1)))
-    srv.refresh()
+    update.apply(srv, params_fn, wkey, -1)
     srv.query(np.arange(min(int(mix["lookups"]["batch"]), n_nodes)))
     setup["warm_update_s"] = clock() - t
 
@@ -251,8 +263,7 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, seed: int,
             if tb >= seconds:
                 break
             with span("bench.update"):
-                srv.update_params(params_fn(wkey, np.int32(i + 2)))
-                srv.refresh()
+                update.apply(srv, params_fn, wkey, i)
             upd.append((tb, clock() - t0))
             tables.append(srv.embeddings)
             i += 1
@@ -294,22 +305,22 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, seed: int,
 
     t = clock()
     nbr, wts = graphs.sample_table(g, edge_w, self_w, sample)
-    checks, failed = _check(cfg, nbr, wts, x, params_fn, wkey, tables,
-                            served, ids, s_check)
+    checks, failed = _check(cfg, model, update, nbr, wts, x, params_fn,
+                            wkey, tables, served, ids, s_check)
     _say(f"reference and comparison: {clock() - t:.3f} s (after the "
          f"window, not in setup_s)")
 
     rows = work.device_rows(nbr, wts, assignment, k if cell["chips"] > 1
                             else 1)
     kind = devices[0].device_kind
+    flops, per_device = model.work(mcfg, n_nodes, rows)
     rec = Records(
         chips=cell["chips"], setup_s=setup_s, window_s=window_s,
         n_updates=n_upd,
         lookup_due=due[:n_lk], lookup_start=np.array(lk_start),
         lookup_end=np.array([sv[3] for sv in served]),
-        model_flops=work.model_flops(n_nodes, sample, dims),
-        work=work.update_work(dims, sample, rows), peaks=peaks.peaks_for(kind),
-        trace=red)
+        model_flops=flops, work=per_device, peaks=peaks.peaks_for(kind),
+        trace=red, widths=model.widths(mcfg), sample=sample, rows=rows)
     metrics = {}
     for m_ in metric_names(spec, cell["name"], traced):
         v = load_reader(m_["name"])(rec)
@@ -334,20 +345,26 @@ def run_cell(spec: dict, cell: dict, cfg: dict, mix: dict, seed: int,
     return result, lines
 
 
-def _check(cfg, nbr, wts, x, params_fn, wkey, tables, served, ids,
-           s_check) -> tuple:
+def _check(cfg, model, update, nbr, wts, x, params_fn, wkey, tables,
+           served, ids, s_check) -> tuple:
     """The numbers compared, each with its limit, and how many answers of
-    the window were wrong. The reference recomputes ``N_CHECKED`` updates
-    drawn from the seed, the last one always among them; every lookup's
-    rows are compared with the table it was served from."""
+    the window were wrong. The model's reference recomputes ``N_CHECKED``
+    updates drawn from the seed, the last one always among them, each from
+    the features and weights the update kind says it served (one call for
+    updates that share their features); every lookup's rows are compared
+    with the table it was served from."""
     limits = cfg["checks"]
     n_upd = len(tables)
     rng = np.random.default_rng(s_check)
     picked = sorted({n_upd - 1, *rng.choice(
         max(n_upd - 1, 0), size=min(N_CHECKED - 1, max(n_upd - 1, 0)),
         replace=False).tolist()}) if n_upd else []
-    params = [params_fn(wkey, np.int32(u + 2)) for u in picked]
-    refs = reference.forward(x, nbr, wts, params, reference.JUDGED)
+    given = [update.served(x, params_fn, wkey, u) for u in picked]
+    refs = []
+    for _, same in itertools.groupby(given, key=lambda g: id(g[0])):
+        same = list(same)
+        refs += model.forward(same[0][0], nbr, wts, [p for _, p in same],
+                              model.JUDGED)
     rms = mx = 0.0 if picked else float("inf")
     bad_updates = 0
     for u, ref in zip(picked, refs):
@@ -358,7 +375,8 @@ def _check(cfg, nbr, wts, x, params_fn, wkey, tables, served, ids,
          f"seed to seed and the control does not read 3x it): {mx:.4e} of "
          f"max|ref|")
     if picked:
-        f32 = reference.forward(x, nbr, wts, params[-1:], "float32")[0]
+        feats, last = given[-1]
+        f32 = model.forward(feats, nbr, wts, [last], "float32")[0]
         _, a32 = reference.gaps(tables[picked[-1]], f32)
         _say(f"update {picked[-1]} against the float32 reference at the "
              f"highest precision (not judged): max gap {a32:.4e} of max|ref|")
@@ -382,7 +400,12 @@ def main(argv=None, t_start: float | None = None) -> int:
     spec = load_spec()
     cell = cell_of(spec, args.workload)
     cfg = config_of(spec, cell["config"])
-    mix = loadgen.load_mix(cell["traffic"])
+    try:
+        mix = loadgen.load_mix(cell["traffic"])
+        kinds_of(cfg, mix)
+    except kinds.Refused as e:
+        _say(f"bench: cell {cell['name']}: {e}")
+        return 2
     try:
         devices = check_devices(cell["chips"])
     except NoChip as e:

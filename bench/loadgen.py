@@ -1,7 +1,8 @@
 """The general traffic generator: reads a mix's parameters from
 ``bench/traffic/<mix>.json`` and draws its arrivals from the seed.
 
-A mix says how updates of the served table run (``update``) and how
+A mix says how updates of the served table run (``update``; its ``kind``
+names a module ``bench/updates/<kind>.py``, ``bench/kinds.py``) and how
 lookups arrive (``lookups``): an arrival process, a rate, a batch size and
 how ids are drawn. Arrivals form a schedule fixed before the window opens,
 so the generator never runs late; each lookup is timed from its due time.
@@ -13,8 +14,9 @@ import os
 
 import numpy as np
 
+from bench import kinds
+
 HERE = os.path.dirname(os.path.abspath(__file__))
-UPDATE_KINDS = ("refresh",)
 ARRIVALS = ("poisson",)
 ID_DISTS = ("uniform",)
 
@@ -22,9 +24,10 @@ ID_DISTS = ("uniform",)
 def load_mix(name: str, root: str = HERE) -> dict:
     with open(os.path.join(root, "traffic", f"{name}.json")) as f:
         mix = json.load(f)
-    if mix["update"]["kind"] not in UPDATE_KINDS:
-        raise ValueError(f"mix {name}: update kind {mix['update']['kind']!r}"
-                         f" not one of {UPDATE_KINDS}")
+    try:
+        kinds.load("updates", mix["update"]["kind"], root)
+    except kinds.Refused as e:
+        raise kinds.Refused(f"mix {name}: {e}") from None
     lk = mix["lookups"]
     if lk["arrival"] not in ARRIVALS or lk["ids"] not in ID_DISTS:
         raise ValueError(f"mix {name}: lookups {lk} not supported "

@@ -1,7 +1,8 @@
 """embed_update_ms: the window divided by the updates completed in it, in
-milliseconds (host clock). One update is ``update_params`` with fresh
-weights and a full ``refresh()`` ending in the host table; the window
-holds the lookups served between updates too."""
+milliseconds (host clock). One update is the mix's update kind
+(``bench/updates/<kind>.py``; ``refresh``: fresh weights and a full
+``refresh()`` ending in the host table); the window holds the lookups
+served between updates too."""
 
 
 def read(r):

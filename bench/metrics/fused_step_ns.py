@@ -1,20 +1,19 @@
-"""fused_step_ns: device time of one grid step of the fused GCN-layer
-kernel, in nanoseconds (profiler trace).
+"""fused_step_ns: device time of the fused GCN-layer kernel per row-slot it
+gathers, in nanoseconds (profiler trace).
 
 The program names the kernel's launches ``fused_ideal_layer``
 (``pallas_call(name=...)``), so the trace names each launch
 ``%fused_ideal_layer.<n>``. Per chip: the summed device time of the
-window's launches of that kernel over the grid steps of the window's
-updates, then the mean over the chips. One grid step gathers one row-slot,
-so an update takes, per chip and layer, its rows times the sample's slots;
-the rows of each layer come from ``work.py``'s FLOPs per chip and the
-widths and sample of the configuration whose model FLOPs per update are
-the run's. A program whose kernels carry no name gives the trace no such
-launch, and the reader returns None.
+window's launches of that kernel over the row-slots the window's updates
+gather, then the mean over the chips. A grid step takes a block of rows
+and all their slots; an update gathers, per chip and layer, the rows the
+chip owns times the sample's slots, from the run's own widths, sample and
+rows (``Records``). A program whose kernels carry no name gives the trace
+no such launch, and the reader returns None.
 """
 import re
 
-from bench import harness, trace, work
+from bench import trace
 
 KERNEL = "fused_ideal_layer"
 _LAUNCH = re.compile(rf"%{KERNEL}(\.\d+)?")
@@ -25,20 +24,12 @@ def is_launch(name: str) -> bool:
 
 
 def row_slots(r) -> list | None:
-    """Per chip, the grid steps of one update: rows x slots, summed over
-    layers. Layer FLOPs per chip are ``2 rows f_in (slots + f_out)``."""
-    spec = harness.load_spec()
-    for c in spec["configs"]:
-        cfg = harness.config_of(spec, c["name"])
-        m = cfg["model"]
-        dims = (m["in_dim"], *m["hidden_dims"], m["out_dim"])
-        s = m["sample"]
-        if work.model_flops(cfg["graph"]["nodes"], s, dims) != r.model_flops:
-            continue
-        return [sum(lw["flops"] / (2.0 * f_in * (s + f_out)) * s
-                    for lw, f_in, f_out in zip(layers, dims[:-1], dims[1:]))
-                for layers in r.work]
-    return None
+    """Per chip, the row-slots of one update: owned rows x slots x layers;
+    None where the record does not say the run's model."""
+    if not r.widths or not r.rows:
+        return None
+    layers = len(r.widths) - 1
+    return [owned * r.sample * layers for owned, _ in r.rows]
 
 
 def read(r):

@@ -17,13 +17,12 @@ from __future__ import annotations
 import argparse
 import contextlib
 import json
-import os
 import time
 from unittest import mock
 
 import jax
 
-from bench import harness, loadgen, peaks, reference
+from bench import harness, kinds, loadgen, peaks
 from bench.faults import FAULTS, broken
 
 NODES = 300
@@ -38,33 +37,26 @@ def tiny(cfg: dict) -> dict:
 
 
 @contextlib.contextmanager
-def steered():
+def steered(kind: str):
+    """The CPU's peaks, and the reference of model kind ``kind`` judged at
+    float32."""
+    model = kinds.load("models", kind)
     with mock.patch.dict(peaks.PEAKS, {"cpu": peaks.PEAKS["TPU v5 lite"]}), \
-            mock.patch.object(reference, "JUDGED", "float32"):
+            mock.patch.object(model, "JUDGED", "float32"):
         yield
-
-
-# a cell whose configuration is ready but that BENCHMARK.json does not
-# hold yet (it has not been measured on the chip)
-PENDING = {"collab-dec4-refresh": {"name": "collab-dec4-refresh",
-                                   "config": "collab-gcn-dec4",
-                                   "traffic": "refresh-serve", "chips": 4}}
 
 
 def run(cell_name: str, fault: str = "none", traced: bool = False,
         seconds: float = 1.0, seed: int = SEED) -> dict:
     """The result line of one rehearsed run."""
     spec = harness.load_spec()
-    names = [w["name"] for w in spec["workloads"]]
-    cell = (harness.cell_of(spec, cell_name) if cell_name in names
-            else PENDING[cell_name])
-    with open(os.path.join(harness.HERE, "configs",
-                           f"{cell['config']}.json")) as f:
-        cfg = tiny(json.load(f))
+    cell = harness.cell_of(spec, cell_name)
+    cfg = tiny(harness.config_of(spec, cell["config"]))
     mix = loadgen.load_mix(cell["traffic"])
     devices = jax.devices()[:cell["chips"]]
     assert len(devices) == cell["chips"], (cell["chips"], jax.devices())
-    with steered(), broken(fault):
+    kind = cfg["model"]["kind"]
+    with steered(kind), broken(fault, kind):
         result, _ = harness.run_cell(spec, cell, cfg, mix, seed, seconds,
                                      traced, devices, time.monotonic())
     return result

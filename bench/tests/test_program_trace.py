@@ -8,10 +8,11 @@ import shutil
 import numpy as np
 import pytest
 
-from bench import harness, peaks, program_trace, trace, work
+from bench import harness, kinds, peaks, program_trace, trace
 
 DEV0 = f"{trace.DEVICE_PREFIX}0"
 DIMS, N, S = (496, 64, 16), 372_475, 8          # collab-gcn
+GCN = kinds.load("models", "gcn")
 CHIP_TRACE = os.path.join(os.path.dirname(__file__), "data",
                           "collab_refresh_5s.xplane.pb.gz")
 
@@ -115,9 +116,10 @@ def _records(red, n_updates=2):
     return harness.Records(
         chips=1, setup_s=1.0, window_s=1.0, n_updates=n_updates,
         lookup_due=np.zeros(0), lookup_start=np.zeros(0),
-        lookup_end=np.zeros(0), model_flops=work.model_flops(N, S, DIMS),
-        work=work.update_work(DIMS, S, [(N, 0)]),
-        peaks=peaks.peaks_for("TPU v5 lite"), trace=red)
+        lookup_end=np.zeros(0), model_flops=GCN.model_flops(N, S, DIMS),
+        work=GCN.update_work(DIMS, S, [(N, 0)]),
+        peaks=peaks.peaks_for("TPU v5 lite"), trace=red,
+        widths=DIMS, sample=S, rows=[(N, 0)])
 
 
 def test_fused_step_ns_reads_the_named_kernel():
@@ -136,7 +138,9 @@ def test_fused_step_ns_reads_the_named_kernel():
     read = harness.load_reader("fused_step_ns")
     assert read(_records(red)) == pytest.approx(176.0, rel=1e-6)
     rec = _records(red)
-    rec.model_flops += 1            # no configuration of the benchmark
+    rec.sample = 2 * S              # the run's own sample: twice the slots
+    assert read(rec) == pytest.approx(88.0, rel=1e-6)
+    rec.widths = ()                 # a record that does not say its model
     assert read(rec) is None
 
 

@@ -120,14 +120,15 @@ def test_chip_trace_busy_kernels_and_gaps(chip_trace):
 
 
 def test_readers_on_the_chip_trace(chip_trace):
-    from bench import peaks, work
+    from bench import kinds, peaks
+    gcn = kinds.load("models", "gcn")
     dims, n = (496, 64, 16), 372_475
     rec = harness.Records(
         chips=1, setup_s=1.0, window_s=5.230591905, n_updates=10,
         lookup_due=np.zeros(0),
         lookup_start=np.zeros(0), lookup_end=np.zeros(0),
-        model_flops=work.model_flops(n, 8, dims),
-        work=work.update_work(dims, 8, [(n, 0)]),
+        model_flops=gcn.model_flops(n, 8, dims),
+        work=gcn.update_work(dims, 8, [(n, 0)]),
         peaks=peaks.peaks_for("TPU v5 lite"), trace=chip_trace)
     read = harness.load_reader
     assert read("device_idle_pct")(rec) == pytest.approx(

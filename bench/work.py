@@ -1,42 +1,12 @@
-"""Work one embedding update needs, computed from shapes alone.
-
-A GCN layer over a padded neighbour sample of S slots aggregates
-``Z = A_hat X`` and transforms ``H = act(Z W + b)``. Per layer:
-
-  model FLOPs       2 N S F_in  (aggregation)  +  2 N F_in F_out  (transform)
-  compulsory bytes  the input rows, read once        (N_in F_in x 4)
-                    the neighbour and weight tables  (N S x (4 + 4))
-                    W                                 (F_in F_out x 4)
-                    the output, written once          (N F_out x 4)
-
-On a mesh each device counts its own rows: N is the rows it owns and N_in
-adds the halo rows it receives. Counted this way the work does not depend
-on what implements it (padding, chunking and tiling are not work), so no
-rewrite of a kernel can push a share of the roofline past 100%.
+"""Work of one embedding update that does not depend on the model kind:
+the rows each device owns and receives, and the least time an update's
+work could take on the chips. The work itself, model FLOPs and per device
+and layer ``{"flops", "bytes"}``, is the model kind's
+(``bench/models/<kind>.py``, ``work``).
 """
 from __future__ import annotations
 
 import numpy as np
-
-ITEM = 4        # float32 features, weights and outputs; int32 indices
-
-
-def layer_flops(n_rows: int, sample: int, f_in: int, f_out: int) -> tuple:
-    """(aggregation, transform) FLOPs of one layer over ``n_rows`` rows."""
-    return 2.0 * n_rows * sample * f_in, 2.0 * n_rows * f_in * f_out
-
-
-def layer_bytes(n_rows: int, n_in_rows: int, sample: int, f_in: int,
-                f_out: int) -> float:
-    """Compulsory HBM bytes of one layer (module docstring)."""
-    return float(ITEM * (n_in_rows * f_in + n_rows * sample * 2
-                         + f_in * f_out + n_rows * f_out))
-
-
-def model_flops(n_rows: int, sample: int, dims) -> float:
-    """Model FLOPs of one update of all ``n_rows`` embeddings."""
-    return sum(sum(layer_flops(n_rows, sample, a, b))
-               for a, b in zip(dims[:-1], dims[1:]))
 
 
 def device_rows(nbr: np.ndarray, wts: np.ndarray, assignment: np.ndarray,
@@ -55,24 +25,6 @@ def device_rows(nbr: np.ndarray, wts: np.ndarray, assignment: np.ndarray,
         remote = np.unique(v[assignment[v] != d])
         out.append((len(rows), len(remote)))
     return out
-
-
-def update_work(dims, sample: int, rows: list) -> list:
-    """Per device, per layer ``{"flops", "bytes"}`` of one update.
-
-    ``rows`` is ``[(owned, halo)]`` per device; one device with no halo is
-    the centralized case.
-    """
-    work = []
-    for owned, halo in rows:
-        layers = []
-        for f_in, f_out in zip(dims[:-1], dims[1:]):
-            layers.append({
-                "flops": sum(layer_flops(owned, sample, f_in, f_out)),
-                "bytes": layer_bytes(owned, owned + halo, sample, f_in,
-                                     f_out)})
-        work.append(layers)
-    return work
 
 
 def ideal_seconds(work: list, peaks: dict) -> list:
